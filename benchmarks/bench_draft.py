@@ -193,7 +193,8 @@ def _kernel_identity_smoke() -> int:
     outs = {}
     for impl in ("ref", "pallas"):
         ml, npr, props = (np.asarray(a) for a in suffix_match_propose(
-            forest, tails, *args, n_prop_max=4, min_match=1, impl=impl))
+            forest, tails, *args, n_prop_max=4, min_match=1, impl=impl,
+            interpret=impl == "pallas"))
         outs[impl] = (ml.tolist(),
                       [props[b, :npr[b]].tolist() for b in range(len(ctxs))])
     assert outs["ref"] == outs["pallas"], outs

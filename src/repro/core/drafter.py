@@ -65,23 +65,19 @@ class DrafterConfig:
     # that tail-risk depth for bounded per-round state (acceptance-only
     # effect — T=0 verification is lossless either way).
     device_tail: int = 64
-    # Packed-forest device layout. "flat" shares the whole concatenated
-    # forest with every kernel grid step (one VMEM residency, fastest
-    # while it fits); "chunked" packs per-tree rows and streams one
-    # tree's chunk HBM->VMEM per row via scalar-prefetch index maps, so
-    # the forest may exceed VMEM as long as the largest single tree
-    # fits. "auto" stays flat on CPU (no VMEM) and on TPU switches to
-    # chunked once the flat estimate passes ``vmem_budget_bytes``
-    # (sticky: it never flips back, to avoid recompile churn).
-    forest_layout: str = "auto"  # auto | flat | chunked
-    vmem_budget_bytes: int = 6 << 20
+    # Packed-forest device layout. "flat" concatenates every tree into
+    # one node table + corpus; "chunked" packs per-tree rows (the layout
+    # the Pallas kernel streams HBM->VMEM one tree at a time). The main
+    # path drafts with the XLA core, which has no VMEM limit, so nothing
+    # switches layouts automatically.
+    forest_layout: str = "flat"  # flat | chunked
 
     def __post_init__(self) -> None:
         if self.scope not in ("problem", "problem+request", "global"):
             raise ValueError(f"unknown drafter scope: {self.scope}")
-        if self.forest_layout not in ("auto", "flat", "chunked"):
+        if self.forest_layout not in ("flat", "chunked"):
             raise ValueError(
-                f"forest_layout must be 'auto'|'flat'|'chunked', "
+                f"forest_layout must be 'flat'|'chunked', "
                 f"got {self.forest_layout!r}"
             )
 
@@ -240,7 +236,6 @@ class BatchedDraftSessions:
         self._min_stride_e = 0
         self._min_stride_c = 0
         self._min_trees = 0
-        self._layout: Optional[str] = None
         # Bumped on every repack: the engine's fused path keys its
         # device roots/forest uploads on this.
         self.repack_version = 0
@@ -324,7 +319,7 @@ class BatchedDraftSessions:
                     del self._packed_by_key[key]  # row recycled away
             keys = list(self._packed_by_key.keys())
             packs = [self._packed_by_key[k] for k in keys]
-            if self._pick_layout(packs) == "chunked":
+            if self.cfg.forest_layout == "chunked":
                 # Per-tree strides floor at the cycle maximum of the
                 # LARGEST tree (same compaction-cycle argument as the
                 # flat floors below, applied per chunk).
@@ -371,26 +366,6 @@ class BatchedDraftSessions:
             self._roots_by_key = {k: int(r) for k, r in zip(keys, roots)}
             self.repack_version += 1
             self.drafter.stats["forest_repacks"] += 1
-
-    def _pick_layout(self, packs) -> str:
-        """Flat vs chunked forest layout (sticky once chunked)."""
-        from repro.kernels.suffix_match import ops as sm_ops
-
-        cfg_layout = self.cfg.forest_layout
-        if cfg_layout != "auto":
-            return cfg_layout
-        if self._layout == "chunked":
-            return "chunked"  # never flip back (recompile churn)
-        import jax
-
-        if (
-            jax.default_backend() == "tpu"
-            and sm_ops.forest_nbytes(packs) > self.cfg.vmem_budget_bytes
-        ):
-            self._layout = "chunked"
-            return "chunked"
-        self._layout = "flat"
-        return "flat"
 
     def prewarm(self) -> None:
         """Refresh packs/forest for every open row's tree NOW.

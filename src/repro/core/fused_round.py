@@ -11,7 +11,7 @@ ping-pong, not compute, bounds the round rate.
 This module fuses the whole steady-state round into one jitted program
 per (K-bucket, forest geometry):
 
-    propose (suffix_match kernel over the packed forest)
+    propose (XLA suffix-match core over the packed forest)
       → build the (B, K+1) verify block on device
       → model forward + ``verify_block`` acceptance
       → cache commit (ring-slot overwrite / staged recurrent gather)
@@ -142,7 +142,7 @@ def emit_scan_device(
 def fused_round_core(
     params, cfg, forest, cache, state: RoundState, roots, budgets, key,
     *, K: int, temperature: float, eos_token: int, recurrent: bool,
-    attn_impl: str, min_match: int, impl: str, interpret: bool,
+    attn_impl: str, min_match: int,
 ):
     """One fused round (traceable): propose → verify → commit → state.
 
@@ -161,7 +161,6 @@ def fused_round_core(
         _, n_prop, props = sm_ops.propose_device(
             forest, state.tails, proots, budgets,
             n_prop_max=K, min_match=min_match,
-            impl=impl, interpret=interpret,
         )
         n_prop = n_prop.astype(i32)
         drafts = jnp.where(
@@ -212,8 +211,7 @@ def fused_round_core(
 
 def build_fused_round(
     cfg, *, K: int, micro_rounds: int, temperature: float, eos_token: int,
-    recurrent: bool, attn_impl: str, min_match: int, impl: str,
-    interpret: bool,
+    recurrent: bool, attn_impl: str, min_match: int,
 ):
     """Jitted fused-round program for one K-bucket.
 
@@ -233,7 +231,7 @@ def build_fused_round(
     core = functools.partial(
         fused_round_core, K=K, temperature=temperature,
         eos_token=eos_token, recurrent=recurrent, attn_impl=attn_impl,
-        min_match=min_match, impl=impl, interpret=interpret,
+        min_match=min_match,
     )
     R = max(1, int(micro_rounds))
 

@@ -211,6 +211,16 @@ def _cache_bucket(n: int) -> int:
     return _round_up(n, 64)
 
 
+def _params_device(params):
+    """The one device ``params`` live on, or None when they span several
+    devices (or are not device arrays yet)."""
+    leaves = jax.tree.leaves(params)
+    if not leaves or not isinstance(leaves[0], jax.Array):
+        return None
+    devs = leaves[0].devices()
+    return next(iter(devs)) if len(devs) == 1 else None
+
+
 def _as_max_new_array(mn, B: int) -> np.ndarray:
     if isinstance(mn, (list, tuple, np.ndarray)):
         arr = np.asarray(mn, np.int64)
@@ -234,6 +244,10 @@ class SpecEngine:
         telemetry=None,
     ) -> None:
         self.params = params
+        # Everything the engine allocates (slot pool, round state, packed
+        # forest) is committed beside the params: a worker whose params
+        # sit on its own chip serves entirely from that chip.
+        self.device = _params_device(params)
         self.cfg = cfg
         self.engine = engine or EngineConfig()
         self.drafter = drafter or SuffixDrafter(DrafterConfig())
@@ -368,6 +382,18 @@ class SpecEngine:
             if vals:
                 hists[cls_i].observe_many(vals)
 
+    def _to_device(self, tree):
+        """Commit host (or default-device) arrays to the params' device."""
+        return jax.device_put(tree, self.device)
+
+    def _init_pool(self, n_slots: int, max_len: int):
+        """Zero slot-pool cache, allocated on the params' device."""
+        with jax.default_device(self.device):
+            cache = M.init_cache(
+                self.cfg, n_slots, max_len, self.engine.cache_headroom
+            )
+        return self._to_device(cache)
+
     # -- jitted device steps ------------------------------------------------
     def _get_prefill(self, Tp: int, max_len: int):
         fn = self._prefill_jit.get((Tp, max_len))
@@ -419,8 +445,6 @@ class SpecEngine:
                 temperature=e.temperature, eos_token=e.eos_token,
                 recurrent=self._recurrent, attn_impl=e.attn_impl,
                 min_match=self.drafter.cfg.min_match,
-                impl="pallas" if jax.default_backend() == "tpu" else "ref",
-                interpret=jax.default_backend() != "tpu",
             )
             self._fused_jit[(K, R)] = fn
         return fn
@@ -644,7 +668,7 @@ class SpecEngine:
             Tp + int(max_new_arr.max(initial=0)) + e.max_draft + 2
         )
         last_logits, cache = self._get_prefill(Tp, max_len)(
-            self.params, jnp.asarray(toks), jnp.asarray(mask)
+            self.params, toks, mask
         )
         key, k0 = jax.random.split(key)
         head = np.array(  # dascheck: disable=DAS001 -- one-time prefill sample download, before the round loop
@@ -879,12 +903,12 @@ class SpecEngine:
         B = len(outputs)
         R = int(e.micro_rounds)
         bds.prewarm()  # pack every open row's tree before round one
-        state = make_state(
+        state = self._to_device(make_state(
             head, bds.tails_matrix(), active, emitted, max_new_arr
-        )
+        ))
         stats.n_h2d += 5
-        forest = bds.forest_arrays()
-        roots_dev = jnp.asarray(bds.roots_array())
+        forest = self._to_device(bds.forest_arrays())
+        roots_dev = self._to_device(bds.roots_array())
         stats.n_h2d += 1
         last_ver = bds.repack_version
         while active.any():
@@ -904,8 +928,8 @@ class SpecEngine:
                     bds.refresh_for(rows)
                     if bds.repack_version != last_ver:
                         last_ver = bds.repack_version
-                        forest = bds.forest_arrays()
-                        roots_dev = jnp.asarray(bds.roots_array())
+                        forest = self._to_device(bds.forest_arrays())
+                        roots_dev = self._to_device(bds.roots_array())
                         stats.n_h2d += 1
                 kv = key
                 if e.temperature > 0:  # greedy verify never uses the key
@@ -1106,7 +1130,7 @@ class SpecEngine:
             max_tp + max(int(r.max_new_tokens) for r in reqs)
             + e.max_draft + 2
         )
-        cache = M.init_cache(self.cfg, n_slots, pool_len, e.cache_headroom)
+        cache = self._init_pool(n_slots, pool_len)
         copy_rows = self._get_copy_rows()
 
         head = np.zeros(n_slots, np.int32)
@@ -1121,14 +1145,14 @@ class SpecEngine:
         # emitted / limits) lives on DEVICE between rounds; the host
         # mirrors above only drive budget solving and bookkeeping.
         state = None
-        forest = None
+        forest = forest_src = None
         roots_dev = None
         last_ver = -1
         if fused:
-            state = make_state(
+            state = self._to_device(make_state(
                 head, np.full((n_slots, bds.tail_len), -1, np.int32),
                 active, emitted, max_new_arr,
-            )
+            ))
             stats.n_h2d += 5
 
         pending = None  # in-flight round (see dispatch/consume)
@@ -1187,7 +1211,7 @@ class SpecEngine:
                     mask[j, Tp - n_p:] = True
                 last_logits, rows_cache = self._get_prefill(
                     Tp, pool_len
-                )(self.params, jnp.asarray(toks), jnp.asarray(mask))
+                )(self.params, toks, mask)
                 stats.n_h2d += 2
                 slots_arr = np.array(
                     [r.slot for r, _ in sub], np.int32
@@ -1620,13 +1644,15 @@ class SpecEngine:
             (admissions). Called from the overlap window so the repack
             and the roots upload hide behind the in-flight round; the
             dispatch-side call is a startup/late-repack fallback."""
-            nonlocal forest, roots_dev, last_ver, roots_dirty
+            nonlocal forest, forest_src, roots_dev, last_ver, roots_dirty
             with tel_obs.span("history_sync") as sp_s:
                 bds.prewarm()
                 last_ver = bds.repack_version
                 roots_dirty = False
-                forest = bds.forest_arrays()
-                roots_dev = jnp.asarray(bds.roots_array())
+                if bds.forest_arrays() is not forest_src:
+                    forest_src = bds.forest_arrays()
+                    forest = self._to_device(forest_src)
+                roots_dev = self._to_device(bds.roots_array())
                 stats.n_h2d += 1
                 sp_s.set(h2d=1)
 
@@ -1897,5 +1923,7 @@ class SpecEngine:
 
     def set_params(self, params) -> None:
         """Policy updated by the learner — the drafter adapts via its
-        sliding window; nothing to retrain (the paper's Insight-3)."""
-        self.params = params
+        sliding window; nothing to retrain (the paper's Insight-3). The
+        new params move to this engine's device."""
+        self.params = params if self.device is None \
+            else self._to_device(params)

@@ -47,9 +47,11 @@ TRUNCATE = "truncate"  # reply with a torn frame, then close
 # ("delay", seconds)   # sleep server-side, then reply normally
 
 
-class JournalCrashError(RuntimeError):
-    """Injected worker death at a journal commit point (RuntimeError so
-    ``MultiWorkerRollout``'s failure path catches it like a real one)."""
+class JournalCrashError(StallError):
+    """Injected worker death at a journal commit point. A worker that
+    dies stops making progress, so it is a ``StallError``:
+    ``MultiWorkerRollout``'s failure path re-queues it like a watchdog
+    expiry."""
 
 
 class FaultPlan:

@@ -11,10 +11,14 @@
   over the flat export of ``SuffixTree.pack()``, one device call per
   verify round instead of B per-row Python walks. kernel.py
   (pl.pallas_call + the shared scalar core), ops.py (forest packing +
-  jit wrapper), ref.py (vmapped reference = the compiled CPU fallback).
+  jit wrapper), ref.py (the vmapped scalar core, which the main path
+  runs on every backend).
 - rglru/: blocked RG-LRU linear-recurrence scan (RecurrentGemma's
   recurrent half) with VMEM carry across sequence chunks.
 
-Validated in interpret mode on CPU (this container); TPU v5e is the
-compile target. Import the subpackages lazily — they pull in pallas.
+Validated through the Pallas interpreter (``interpret=True``) in the CPU
+tests. The TPU lowering refuses all three as written — block shapes off
+the 8x128 tiling, and the suffix-match core indexing vector-loaded
+tables at data-dependent positions — so no main-path code selects them.
+Import the subpackages lazily — they pull in pallas.
 """

@@ -2,7 +2,8 @@
 
 Takes the model-layer quantities (x, r, i, Λ, h0), precomputes the
 kernel inputs (gated input, log-a), pads T to the time-chunk and W to
-the width-block, and dispatches (interpret mode on CPU)."""
+the width-block, and dispatches: compiled on TPU, through the Pallas
+interpreter only when the caller passes ``interpret=True``."""
 
 from __future__ import annotations
 
@@ -13,8 +14,6 @@ import jax.numpy as jnp
 
 from .kernel import RGLRU_C, rglru_scan_kernel
 
-_INTERPRET = jax.default_backend() == "cpu"
-
 
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
@@ -22,10 +21,8 @@ def _round_up(n: int, m: int) -> int:
 
 # das: hot-path
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rglru_scan(x, r, i, lam, h0, *, interpret: bool | None = None):
+def rglru_scan(x, r, i, lam, h0, *, interpret: bool = False):
     """x, r, i: (B,T,W) fp32; lam (W,); h0 (B,W). → (h_seq, h_final)."""
-    if interpret is None:
-        interpret = _INTERPRET
     B, T, W = x.shape
     a_base = jnp.log(jax.nn.sigmoid(lam))
     log_a = RGLRU_C * r * a_base[None, None, :]

@@ -7,20 +7,18 @@ world and the kernel's MXU-aligned tiles:
   kv head sees a contiguous (T·G, hd) query block;
 * padding: query rows to the 8-row sublane tile, cache length to a
   multiple of the KV chunk (padded slots carry cpos = -1 → masked);
-* interpret mode on CPU (this container) vs compiled mode on real TPU.
+* compiled on TPU; ``interpret=True`` runs the kernel through the Pallas
+  interpreter, which a CPU caller asks for explicitly.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from .kernel import DEFAULT_CHUNK, spec_verify_attention_kernel
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def _round_up(n: int, m: int) -> int:
@@ -41,10 +39,8 @@ def spec_verify_attention(
     window: int = 0,
     softcap: float = 0.0,
     chunk: int = DEFAULT_CHUNK,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    if interpret is None:
-        interpret = _INTERPRET
     B, T, Hq, hd = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv

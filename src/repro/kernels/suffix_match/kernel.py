@@ -58,11 +58,13 @@ removing B synchronous host walks (and their resync re-feeds after
 every tree mutation) from the verify loop, so the propose dispatch
 overlaps the in-flight verify in the double-buffered continuous loop.
 The scalar core (``match_propose_row``) is shared verbatim with the
-pure-jnp reference (``ref.py``), which doubles as the compiled CPU
-fallback; the pallas path is validated in interpret mode on CPU (this
-container) and compiles for TPU where the forest fits VMEM (~a few MB
-for production window sizes; corpus chunking via HBM→VMEM DMA is the
-documented follow-up for larger forests).
+pure-jnp reference (``ref.py``), which is what the main path runs on
+every backend; the pallas path is validated in interpret mode on CPU.
+The TPU lowering refuses it: the ``(None, m)`` / ``(1,)`` blocks break
+the 8x128 tiling, and the core indexes tables loaded as vector values
+(``en_ref[...]``) at data-dependent positions, a ``dynamic_slice``
+Mosaic cannot lower. The same lookup compiles from an SMEM ref, so a
+TPU kernel means moving the tables into SMEM.
 
 Invariants inherited from ``SuffixTree.pack()``:
 * canonical positions are kept eagerly normalized: the matcher is

@@ -9,9 +9,19 @@ Handles the host-side plumbing between the drafter's per-problem
   windows grow), returning the per-tree root indices;
 * ``suffix_match_propose`` — one device call for a ``(B, m)`` batch of
   context tails: longest-suffix match length + up to ``n_prop_max``
-  greedy continuation tokens per row. Dispatches the pallas kernel on
-  TPU, the jitted pure-jnp reference on CPU (identical semantics;
-  ``impl="pallas"`` with ``interpret=True`` validates the kernel in CI).
+  greedy continuation tokens per row.
+
+The main path drafts with the XLA scalar core (``impl="ref"``, the
+vmapped ``match_propose_row``) on every backend: it is the default of
+``propose_device`` (inside the fused round) and of
+``suffix_match_propose``, and nothing on the main path passes another
+``impl``. The TPU lowering refuses the Pallas
+kernel: its ``(None, m)`` / ``(1,)`` blocks break the 8x128 tiling
+rule, and the core indexes tables loaded as vector values at
+data-dependent positions (a ``dynamic_slice`` Mosaic cannot lower) — a
+working TPU kernel needs the tables in SMEM refs. ``impl="pallas"``
+with ``interpret=True`` keeps the kernel validated against the core in
+the CPU tests.
 """
 
 from __future__ import annotations
@@ -230,7 +240,7 @@ def _propose_chunked_ref(forest, tails, roots, budgets, *, n_prop_max,
 
 # das: hot-path — trace-time dispatch, composed inside the fused round
 def propose_device(forest, tails, roots, budgets, *, n_prop_max,
-                   min_match, impl, interpret):
+                   min_match, impl="ref", interpret=False):
     """Trace-time propose dispatch — usable standalone *or inside a
     larger jitted program* (the fused verify round composes it with the
     model forward). Routes on forest layout: flat forests use the
@@ -295,21 +305,17 @@ def suffix_match_propose(
     *,
     n_prop_max: int,
     min_match: int = 1,
-    impl: str | None = None,
-    interpret: bool | None = None,
+    impl: str = "ref",
+    interpret: bool = False,
     query: np.ndarray | None = None,  # pre-packed (B, m+2) override
 ):
     """Batched longest-suffix match + greedy continuation proposal.
 
     Returns ``(match_len (B,), n_prop (B,), props (B, n_prop_max))`` as
     device arrays (callers keep the dispatch/consume split to overlap
-    with the in-flight verify). ``impl``: "pallas" | "ref" | None
-    (auto: pallas on TPU, the jitted jnp reference elsewhere).
+    with the in-flight verify). ``impl``: "ref" (the XLA scalar core,
+    the main path) | "pallas" (tests only, with ``interpret=True``).
     """
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if query is None:
         query = pack_query(tails, roots, budgets)
     # the numpy query crosses into jax inside the jitted call (the C++

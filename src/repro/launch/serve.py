@@ -1,18 +1,31 @@
 """Production launcher: serving entry point (decode/verify workloads).
 
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+        --continuous --slots 32 --requests 64
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --smoke
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --smoke \
         --continuous [--slots 4] [--requests 16]
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --dry-run \
         [--shape verify_8] [--multi-pod]
 
-``--smoke`` runs real batched speculative serving of the reduced config
-on CPU (suffix-tree drafter warmed by repeated requests). With
-``--continuous`` the request stream flows through the slot-recycling
-pool (``--slots`` device rows, longest-predicted-first admission) and
-completions are logged as they stream out — the serving shape for
-heavy traffic. ``--dry-run`` lowers+compiles the full config's serve
-step on the production mesh.
+Without ``--smoke`` the server runs the **published config** of
+``--arch`` at full width (every layer, the full vocabulary, bf16 weights
+from a seed) under GRPO-shaped traffic: ``--requests`` rollouts in
+groups of ``GROUP`` that share one prompt of 100–128 tokens, with
+heavy-tailed ``max_new_tokens`` from 64 to 2,048. That is the shape for
+an accelerator; ``chip_smoke.py`` at the repository root drives the same
+functions on one TPU. ``--smoke`` serves the reduced variant
+(``configs.smoke_variant``) with short prompts and outputs, which runs
+on a CPU in seconds.
+
+Each round serves the same problems again (RL epochs), so from the
+second round on the suffix-tree drafter proposes from history. With
+``--continuous`` the requests flow through the slot-recycling pool
+(``SpecEngine.serve``: ``--slots`` device rows, longest-predicted-first
+admission, fused device rounds with the default ``--scope problem``) and
+completions are logged as they stream out; without it each round is one
+lock-step ``generate`` batch of ``--batch`` requests. ``--dry-run``
+lowers+compiles the full config's serve step on the production mesh.
 
 ``--history-dir DIR`` points the server at a persisted rollout history
 (``repro.history.persist`` format): the drafter starts with warm suffix
@@ -21,18 +34,20 @@ first requests draft against cross-epoch history instead of cold
 trees. ``--save-history`` persists the (updated) history back to the
 same directory on exit — run-to-run the server keeps learning.
 
-``--history-service`` runs the smoke through the **sharded cross-worker
-history service**: ``--shards`` shard subprocesses (each owning a
-contiguous problem range behind the socket RPC) and ``--workers``
-serving engines whose drafters publish rollouts to — and replicate
-packed-forest deltas from — the shared service, so every worker drafts
-from every worker's rollouts. Needs a tree-only ``--scope`` (problem or
-global). Combined with ``--history-dir`` the service loads/saves the
+``--history-service`` runs through the **sharded cross-worker history
+service**: ``--shards`` shard subprocesses (each owning a problem range
+behind the socket RPC) and ``--workers`` serving engines, one per
+device round-robin over ``jax.devices()``, whose drafters publish
+rollouts to — and replicate packed-forest deltas from — the shared
+service, so every worker drafts from every worker's rollouts. Each round
+partitions the problems across the workers (rotated), and the workers
+serve their slices concurrently. Needs a tree-only ``--scope`` (problem
+or global). Combined with ``--history-dir`` the service loads/saves the
 sharded manifest format (``history_manifest.json`` +
 ``history.shard<k>.json``).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
-        --smoke --history-service --shards 2 --workers 2 --scope problem
+        --smoke --history-service --shards 2 --workers 2
 
 **Observability** — ``--metrics-port P`` attaches a ``repro.obs``
 ``Telemetry`` (metrics registry + round-phase tracer + event log) and
@@ -48,8 +63,17 @@ from __future__ import annotations
 
 import argparse
 import logging
+import time
 
 log = logging.getLogger("repro.launch.serve")
+
+# GRPO group size: rollouts per problem, all sharing the problem's prompt.
+GROUP = 8
+# Generated traffic, inclusive (low, high) ranges per request. Random
+# weights rarely emit EOS, so ``max_new_tokens`` sets each rollout's
+# length: a Pareto(1) tail from the low end, capped at the high end.
+SMOKE_TRAFFIC = {"prompt_len": (5, 8), "max_new": (8, 32)}
+GRPO_TRAFFIC = {"prompt_len": (100, 128), "max_new": (64, 2048)}
 
 
 def _setup_logging() -> None:
@@ -58,6 +82,165 @@ def _setup_logging() -> None:
             level=logging.INFO,
             format="%(asctime)s %(name)s %(levelname)s %(message)s",
         )
+
+
+def load_model(arch: str, *, smoke: bool, seed: int = 0):
+    """``(cfg, params)`` for ``arch``: the published config (full width,
+    its own dtype — bf16 for the decoder configs) or, with ``smoke``,
+    its reduced variant. Weights are random, drawn from ``seed`` on the
+    default device op by op: one jitted program for the whole
+    unrolled init compiles for over a minute at full width."""
+    import jax
+
+    from repro.configs import get_config, smoke_variant
+    from repro.models import model as M
+    from repro.models.layers import split_tree
+
+    cfg = get_config(arch)
+    if smoke:
+        cfg = smoke_variant(cfg)
+    if cfg.is_encoder_decoder:
+        raise SystemExit(
+            "enc-dec serving isn't wired through SpecEngine; use "
+            "tests/test_models.py::test_encoder_decoder_consistency or "
+            "the dry-run path"
+        )
+    params, _ = split_tree(M.init_params(cfg, jax.random.key(seed)))
+    return cfg, params
+
+
+def make_engine(params, cfg, *, scope: str = "problem", spec: bool = True,
+                fuse: str = "auto", remote=None, telemetry=None):
+    """The serving engine: greedy verification of up to 8 drafted tokens
+    per round (K buckets 0/4/8) from a suffix-tree drafter. ``spec=False``
+    is plain decoding through the same engine, the reference that
+    speculative output must match token for token. The engine serves
+    from the device its ``params`` are committed to."""
+    from repro.core.drafter import DrafterConfig, SuffixDrafter
+    from repro.core.spec_engine import EngineConfig, SpecEngine
+
+    return SpecEngine(
+        params, cfg,
+        EngineConfig(spec_enabled=spec, max_new_tokens=32, eos_token=1,
+                     max_draft=8, block_buckets=(0, 4, 8),
+                     fuse_rounds=fuse),
+        drafter=SuffixDrafter(
+            DrafterConfig(scope=scope, min_match=2), remote=remote
+        ),
+        telemetry=telemetry,
+    )
+
+
+def grpo_requests(seed: int, *, n_problems: int, vocab: int,
+                  prompt_len, max_new, group: int = GROUP):
+    """GRPO-shaped requests: ``n_problems`` prompts of random tokens,
+    each served as ``group`` rollouts that share it. The same ``seed``
+    gives the same problems, so calling again serves the next epoch."""
+    import numpy as np
+
+    from repro.core.scheduler import Request
+
+    rng = np.random.default_rng(seed)
+    lo, hi = max_new
+    reqs = []
+    for p in range(n_problems):
+        n = int(rng.integers(prompt_len[0], prompt_len[1] + 1))
+        prompt = rng.integers(4, vocab, size=n).tolist()
+        caps = np.minimum(hi, np.floor(lo * (1.0 + rng.pareto(1.0, group))))
+        for cap in caps:
+            reqs.append(Request(
+                rid=len(reqs), problem_id=f"p{p}", prompt=prompt,
+                max_new_tokens=int(cap),
+            ))
+    return reqs
+
+
+def serve_epoch(eng, reqs, *, slots: int, key, watchdog=None, journal=None,
+                drain=None, log_done: bool = False):
+    """Serve ``reqs`` through the engine's slot pool to completion.
+
+    Returns ``(finished requests in completion order, RolloutStats, wall
+    seconds)``. The outputs are host token lists, so the wall time ends
+    after the device produced the last token."""
+    from repro.core.spec_engine import RolloutStats
+
+    st = RolloutStats()
+    done = []
+    t0 = time.perf_counter()
+    for fin in eng.serve(reqs, slots=slots, key=key, stats=st,
+                         watchdog=watchdog, journal=journal, drain=drain):
+        done.append(fin)
+        if log_done:
+            log.info(
+                "  req %3d (%s) done: %4d toks, rounds %d->%d",
+                fin.rid, fin.problem_id, len(fin.output), fin.admit_round,
+                fin.finish_round,
+            )
+    return done, st, time.perf_counter() - t0
+
+
+def make_workers(params, cfg, book, *, n_workers: int, scope: str = "problem",
+                 fuse: str = "auto", telemetries=None):
+    """One engine per worker, each with a drafter backed by the history
+    service at ``book``. Worker ``w`` serves from ``jax.devices()[w]``
+    (round-robin when there are fewer devices): its params copy and
+    everything the engine allocates live on that device. Returns
+    ``(engines, clients)``."""
+    import jax
+
+    from repro.history.client import HistoryClient
+
+    devs = jax.devices()
+    engines, clients = [], []
+    for w in range(n_workers):
+        client = HistoryClient(book, worker_id=f"w{w}")
+        tel = telemetries[w] if telemetries is not None else None
+        if tel is not None and tel.enabled:
+            client.attach_telemetry(tel)
+        engines.append(make_engine(
+            jax.device_put(params, devs[w % len(devs)]), cfg, scope=scope,
+            fuse=fuse, remote=client, telemetry=tel,
+        ))
+        clients.append(client)
+    return engines, clients
+
+
+def serve_workers(engines, clients, reqs, *, slots: int, key, rnd: int = 0,
+                  watchdogs=None):
+    """Serve ``reqs`` across the workers, one thread each: problem ``j``
+    (in first-appearance order) goes to worker ``(j + rnd) % N`` — rotated
+    per round, so every worker drafts from peers' history. Each worker
+    flushes its publishes to the service after its slice. Returns
+    ``(finished requests sorted by rid, per-worker RolloutStats, wall
+    seconds)``."""
+    import concurrent.futures
+
+    import jax
+
+    N = len(engines)
+    order = list(dict.fromkeys(r.problem_id for r in reqs))
+    owner = {pid: (j + rnd) % N for j, pid in enumerate(order)}
+    slices = [[r for r in reqs if owner[r.problem_id] == w]
+              for w in range(N)]
+    keys = jax.random.split(key, N)
+
+    def run(w):
+        if not slices[w]:
+            return [], None
+        done, st, _ = serve_epoch(
+            engines[w], slices[w], slots=slots, key=keys[w],
+            watchdog=watchdogs[w] if watchdogs else None,
+        )
+        clients[w].flush()
+        return done, st
+
+    t0 = time.perf_counter()
+    with concurrent.futures.ThreadPoolExecutor(N) as ex:
+        results = [f.result() for f in [ex.submit(run, w) for w in range(N)]]
+    dt = time.perf_counter() - t0
+    finished = sorted((r for done, _ in results for r in done),
+                      key=lambda r: r.rid)
+    return finished, [st for _, st in results], dt
 
 
 def _make_telemetry(args, worker: int = 0):
@@ -98,28 +281,39 @@ def _export_trace(args, tels, names=None) -> None:
 
 
 def main() -> None:
-    ap = argparse.ArgumentParser()
+    ap = argparse.ArgumentParser(
+        description="Serve an --arch config through the speculative "
+                    "rollout engine: the published config at full width "
+                    "(bf16, random weights), or its reduced variant "
+                    "with --smoke."
+    )
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced variant with short traffic "
+                         "(CPU-sized); default: the published config at "
+                         "full width with GRPO-shaped traffic")
     ap.add_argument("--dry-run", action="store_true")
     ap.add_argument("--shape", default="decode_32k",
                     choices=["decode_32k", "long_500k", "verify_8"])
     ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--rounds", type=int, default=3)
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--rounds", type=int, default=3,
+                    help="epochs: each serves the same problems again")
+    ap.add_argument("--batch", type=int, default=8,
+                    help="requests per lock-step generate batch")
     ap.add_argument("--continuous", action="store_true",
                     help="serve through the slot-recycling pool")
     ap.add_argument("--slots", type=int, default=4,
                     help="device slots in the continuous pool")
     ap.add_argument("--requests", type=int, default=0,
-                    help="requests per round in continuous mode "
+                    help="requests per round in continuous and "
+                         f"multi-worker mode, in groups of {GROUP} "
                          "(default: 2x --batch)")
     ap.add_argument("--fuse", default="auto",
                     choices=["auto", "on", "off"],
                     help="fused device-resident rounds (one dispatch "
                          "per verify round); 'off' keeps the unfused "
                          "multi-dispatch fallback")
-    ap.add_argument("--scope", default="problem+request",
+    ap.add_argument("--scope", default="problem",
                     choices=["problem", "problem+request", "global"],
                     help="drafter scope (fused rounds need a tree-only "
                          "scope: problem or global)")
@@ -135,7 +329,8 @@ def main() -> None:
     ap.add_argument("--shards", type=int, default=2,
                     help="history-service shard count")
     ap.add_argument("--workers", type=int, default=2,
-                    help="serving workers sharing the history service")
+                    help="serving workers sharing the history service, "
+                         "one per device round-robin")
     ap.add_argument("--service-mode", default="process",
                     choices=["process", "thread"],
                     help="spawn shards as subprocesses (real runs) or "
@@ -175,6 +370,8 @@ def main() -> None:
                  "--scope problem (or global)")
 
     if args.dry_run:
+        # The child owns the process's devices: spawn it before this
+        # process touches JAX.
         import subprocess
         import sys
 
@@ -187,37 +384,17 @@ def main() -> None:
         raise SystemExit(subprocess.call(cmd))
 
     _setup_logging()
+    from repro.launch.compile_cache import configure_compile_cache
 
-    import jax
-    import numpy as np
+    log.info("compilation cache: %s", configure_compile_cache())
 
-    from repro.configs import get_config, smoke_variant
-    from repro.core.drafter import DrafterConfig, SuffixDrafter
-    from repro.core.spec_engine import EngineConfig, SpecEngine
-    from repro.models import model as M
-    from repro.models.layers import split_tree
-
-    cfg = smoke_variant(get_config(args.arch))
-    if cfg.is_encoder_decoder:
-        raise SystemExit(
-            "enc-dec serving smoke isn't wired through SpecEngine; use "
-            "tests/test_models.py::test_encoder_decoder_consistency or "
-            "the dry-run path"
-        )
-    params, _ = split_tree(M.init_params(cfg, jax.random.key(0)))
+    cfg, params = load_model(args.arch, smoke=args.smoke)
     if args.history_service:
         _serve_with_service(args, cfg, params)
         return
     tel, metrics_server = _make_telemetry(args)
-    eng = SpecEngine(
-        params, cfg,
-        EngineConfig(spec_enabled=True, max_new_tokens=32, eos_token=1,
-                     max_draft=8, block_buckets=(0, 4, 8),
-                     fuse_rounds=args.fuse),
-        drafter=SuffixDrafter(DrafterConfig(scope=args.scope,
-                                            min_match=2)),
-        telemetry=tel,
-    )
+    eng = make_engine(params, cfg, scope=args.scope, fuse=args.fuse,
+                      telemetry=tel)
     if args.history_dir:
         import os
 
@@ -253,9 +430,8 @@ def main() -> None:
         drain = DrainController(
             args.drain_deadline, telemetry=tel
         ).install()
-    rng = np.random.default_rng(0)
     try:
-        _serve_rounds(args, eng, rng, tel, journal=journal, drain=drain,
+        _serve_rounds(args, eng, tel, journal=journal, drain=drain,
                       recovered=recovered)
     finally:
         # Persist whatever history accumulated, interrupted or not —
@@ -268,6 +444,15 @@ def main() -> None:
         _export_trace(args, [tel])
         if metrics_server is not None:
             metrics_server.stop()
+
+
+def _traffic(args, n_requests: int):
+    """``grpo_requests`` arguments for a round of ``n_requests`` (the
+    same problems every round)."""
+    cfg_traffic = SMOKE_TRAFFIC if args.smoke else GRPO_TRAFFIC
+    return dict(
+        n_problems=max(1, n_requests // GROUP), **cfg_traffic
+    )
 
 
 def _open_journal(args, tel):
@@ -314,18 +499,14 @@ def _log_round(args, tel, rnd: int, msg: str, *fmt_args, **event) -> None:
 def _serve_with_service(args, cfg, params) -> None:
     """Multi-worker serving over the sharded history service: shards as
     subprocesses (or threads with ``--service-mode thread``), one engine
-    per worker, each round's request stream partitioned across workers
-    (rotated, so every worker ends up drafting from peers' history)."""
+    per worker on its own device, each round's problems partitioned
+    across workers (rotated, so every worker ends up drafting from
+    peers' history)."""
     import os
-    import time
 
     import jax
-    import numpy as np
 
-    from repro.core.drafter import DrafterConfig, SuffixDrafter
-    from repro.core.spec_engine import EngineConfig, SpecEngine
     from repro.history import persist
-    from repro.history.client import HistoryClient
     from repro.history.service import HistoryService
 
     states = None
@@ -380,72 +561,50 @@ def _serve_with_service(args, cfg, params) -> None:
 
         supervisor = ShardSupervisor(svc, seed=0, telemetry=tels[0])
         supervisor.start(interval_s=1.0)
-    watchdogs = []
-    engines, clients = [], []
-    for w in range(args.workers):
-        # svc.book is live: a supervised restart republishes the new
-        # shard address to every client without reconstructing them.
-        client = HistoryClient(svc.book, worker_id=f"w{w}")
-        if tels[w].enabled:
-            client.attach_telemetry(tels[w])
-        engines.append(SpecEngine(
-            params, cfg,
-            EngineConfig(spec_enabled=True, max_new_tokens=32, eos_token=1,
-                         max_draft=8, block_buckets=(0, 4, 8),
-                         fuse_rounds=args.fuse),
-            drafter=SuffixDrafter(
-                DrafterConfig(scope=args.scope, min_match=2), remote=client
-            ),
-            telemetry=tels[w],
-        ))
-        engines[-1].epoch = engines[-1].drafter.epoch = epoch0
-        clients.append(client)
-        if args.watchdog_deadline > 0:
-            from repro.fault.watchdog import RolloutWatchdog
+    # svc.book is live: a supervised restart republishes the new shard
+    # address to every client without reconstructing them.
+    engines, clients = make_workers(
+        params, cfg, svc.book, n_workers=args.workers, scope=args.scope,
+        fuse=args.fuse, telemetries=tels,
+    )
+    watchdogs = None
+    if args.watchdog_deadline > 0:
+        from repro.fault.watchdog import RolloutWatchdog
 
-            watchdogs.append(RolloutWatchdog(
-                args.watchdog_deadline, flight=tels[w].flight
-            ))
-        else:
-            watchdogs.append(None)
+        watchdogs = [
+            RolloutWatchdog(args.watchdog_deadline, flight=tels[w].flight)
+            for w in range(args.workers)
+        ]
+    for eng in engines:
+        eng.epoch = eng.drafter.epoch = epoch0
     log.info(
         "history service: %d shard(s) [%s] x %d worker(s) at %s",
         args.shards, args.service_mode, args.workers, svc.addresses,
     )
-    rng = np.random.default_rng(0)
+    n_req = args.requests or 2 * args.batch
     try:
-        base_epoch = max(e.epoch for e in engines)
         for rnd in range(args.rounds):
-            t0 = time.perf_counter()
-            fwd = acc = rds = 0
-            for w, eng in enumerate(engines):
-                prompts, pids = [], []
-                for b in range(args.batch):
-                    # rotated partition: worker w serves different
-                    # problems each round, drafting from peers' history
-                    seed = (b + w + rnd) % 4
-                    prompts.append(
-                        [2] + list(rng.integers(4, 20, size=4 + seed))
-                    )
-                    pids.append(f"q{seed}")
-                outs, st = eng.generate(
-                    prompts, pids, key=jax.random.key(rnd * 31 + w),
-                    watchdog=watchdogs[w],
-                )
-                clients[w].flush()
-                fwd += st.n_fwd
-                acc += st.n_accepted
-                rds += st.n_rounds
-            dt = time.perf_counter() - t0
+            reqs = grpo_requests(0, vocab=cfg.vocab_size,
+                                 **_traffic(args, n_req))
+            done, stats, dt = serve_workers(
+                engines, clients, reqs, slots=args.slots,
+                key=jax.random.key(rnd), rnd=rnd, watchdogs=watchdogs,
+            )
+            stats = [st for st in stats if st is not None]
+            toks = sum(len(r.output) for r in done)
+            acc = sum(st.n_accepted for st in stats)
+            rds = sum(st.n_rounds for st in stats)
             _log_round(
                 args, tels[0], rnd,
-                "round %d: %8.1f ms  fwd=%4d accept/round=%6.2f",
-                rnd, dt * 1e3, fwd, acc / max(rds, 1),
-                ms=dt * 1e3, fwd=fwd,
+                "round %d: %8.1f ms  %d reqs x %d worker(s) tok/s=%7.1f "
+                "accept/round=%6.2f",
+                rnd, dt * 1e3, len(reqs), len(engines),
+                toks / max(dt, 1e-9), acc / max(rds, 1),
+                ms=dt * 1e3, reqs=len(reqs), tok_per_s=toks / max(dt, 1e-9),
                 accept_per_round=acc / max(rds, 1),
             )
             for eng in engines:
-                eng.begin_iteration(base_epoch + rnd + 1)
+                eng.begin_iteration(epoch0 + rnd + 1)
         if args.history_dir and args.save_history:
             for c in clients:
                 c.flush()
@@ -466,7 +625,7 @@ def _serve_with_service(args, cfg, params) -> None:
                 srv.stop()
 
 
-def _resume_recovered(args, eng, tel, journal, drain, recovered) -> None:
+def _resume_recovered(args, eng, journal, drain, recovered) -> None:
     """Serve the journal's unfinished sessions to completion before any
     new traffic: prompts/budgets come from the journal's begin records,
     salvaged tokens re-enter via prefix re-prefill (token-identical at
@@ -474,7 +633,6 @@ def _resume_recovered(args, eng, tel, journal, drain, recovered) -> None:
     import jax
 
     from repro.core.scheduler import Request
-    from repro.core.spec_engine import RolloutStats
     from repro.fault.journal import resume_requests
 
     reqs = [
@@ -492,20 +650,17 @@ def _resume_recovered(args, eng, tel, journal, drain, recovered) -> None:
     )
     if not to_serve:
         return
-    st = RolloutStats()
-    for fin in eng.serve(to_serve, slots=args.slots,
-                         key=jax.random.key(0xD5), stats=st,
-                         journal=journal, drain=drain):
+    for fin in serve_epoch(eng, to_serve, slots=args.slots,
+                           key=jax.random.key(0xD5), journal=journal,
+                           drain=drain)[0]:
         log.info(
             "  resumed req %3d (%s) done: %3d toks (state %s)",
             fin.rid, fin.problem_id, len(fin.output), fin.state,
         )
 
 
-def _serve_rounds(args, eng, rng, tel, journal=None, drain=None,
+def _serve_rounds(args, eng, tel, journal=None, drain=None,
                   recovered=None) -> None:
-    import time
-
     import jax
 
     # Continue the (possibly warm-restored) epoch cursor instead of
@@ -514,73 +669,50 @@ def _serve_rounds(args, eng, rng, tel, journal=None, drain=None,
     base_epoch = eng.epoch
 
     if recovered:
-        _resume_recovered(args, eng, tel, journal, drain, recovered)
+        _resume_recovered(args, eng, journal, drain, recovered)
 
-    if args.continuous:
-        from repro.core.scheduler import Request
-        from repro.core.spec_engine import RolloutStats
-
-        n_req = args.requests or 2 * args.batch
-        for rnd in range(args.rounds):
-            reqs = []
-            for i in range(n_req):
-                seed = i % 4
-                reqs.append(Request(
-                    rid=i, problem_id=f"q{seed}",
-                    prompt=[2] + list(rng.integers(4, 20, size=4 + seed)),
-                    max_new_tokens=8 * (1 + seed),  # long-tailed stream
-                ))
-            st = RolloutStats()
-            t0 = time.perf_counter()
-            for fin in eng.serve(reqs, slots=args.slots,
-                                 key=jax.random.key(rnd), stats=st,
-                                 journal=journal, drain=drain):
-                log.info(
-                    "  req %3d (%s) done: %3d toks, rounds %d->%d",
-                    fin.rid, fin.problem_id, len(fin.output),
-                    fin.admit_round, fin.finish_round,
-                )
-            dt = time.perf_counter() - t0
+    n_req = (args.requests or 2 * args.batch) if args.continuous \
+        else args.batch
+    for rnd in range(args.rounds):
+        reqs = grpo_requests(0, vocab=eng.cfg.vocab_size,
+                             **_traffic(args, n_req))
+        key = jax.random.key(rnd)
+        if args.continuous:
+            done, st, dt = serve_epoch(
+                eng, reqs, slots=args.slots, key=key, journal=journal,
+                drain=drain, log_done=True,
+            )
             toks = st.n_toks_emitted
             _log_round(
                 args, tel, rnd,
                 "round %d: %8.1f ms  %d reqs / %d slots  makespan=%d "
                 "rounds fwd=%4d tok/s=%7.1f accept/round=%6.2f",
-                rnd, dt * 1e3, n_req, args.slots, st.n_rounds, st.n_fwd,
+                rnd, dt * 1e3, len(reqs), args.slots, st.n_rounds, st.n_fwd,
                 toks / max(dt, 1e-9), st.acceptance_per_round,
-                ms=dt * 1e3, reqs=n_req, fwd=st.n_fwd,
+                ms=dt * 1e3, reqs=len(reqs), fwd=st.n_fwd,
                 tok_per_s=toks / max(dt, 1e-9),
                 accept_per_round=st.acceptance_per_round,
             )
-            if drain is not None and drain.draining:
-                log.info(
-                    "drain (%s): stopping after round %d; unfinished "
-                    "progress is journaled", drain.reason, rnd,
-                )
-                break
-            eng.begin_iteration(base_epoch + rnd + 1)
-        return
-
-    for rnd in range(args.rounds):
-        prompts, pids = [], []
-        for b in range(args.batch):
-            seed = b % 4
-            prompts.append([2] + list(rng.integers(4, 20, size=4 + seed)))
-            pids.append(f"q{seed}")
-        t0 = time.perf_counter()
-        outs, st = eng.generate(prompts, pids, key=jax.random.key(rnd),
-                                journal=journal)
-        dt = time.perf_counter() - t0
-        _log_round(
-            args, tel, rnd,
-            "round %d: %8.1f ms fwd=%4d accept/round=%6.2f",
-            rnd, dt * 1e3, st.n_fwd, st.acceptance_per_round,
-            ms=dt * 1e3, fwd=st.n_fwd,
-            accept_per_round=st.acceptance_per_round,
-        )
+        else:
+            t0 = time.perf_counter()
+            _, st = eng.generate(
+                [r.prompt for r in reqs], [r.problem_id for r in reqs],
+                max_new_tokens=[r.max_new_tokens for r in reqs], key=key,
+                journal=journal,
+            )
+            dt = time.perf_counter() - t0
+            _log_round(
+                args, tel, rnd,
+                "round %d: %8.1f ms fwd=%4d accept/round=%6.2f",
+                rnd, dt * 1e3, st.n_fwd, st.acceptance_per_round,
+                ms=dt * 1e3, fwd=st.n_fwd,
+                accept_per_round=st.acceptance_per_round,
+            )
         if drain is not None and drain.draining:
-            log.info("drain (%s): stopping after round %d",
-                     drain.reason, rnd)
+            log.info(
+                "drain (%s): stopping after round %d; unfinished "
+                "progress is journaled", drain.reason, rnd,
+            )
             break
         eng.begin_iteration(base_epoch + rnd + 1)
 

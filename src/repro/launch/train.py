@@ -4,9 +4,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch mixtral-8x7b \
         --dry-run  # lower + compile the train step on the target mesh
 
-On this CPU container only ``--smoke`` (reduced config, real training on
-a synthetic task) and ``--dry-run`` are practical; on a real TPU pod the
-same entry point runs the full config.
+``--smoke`` trains the reduced config on a synthetic task; ``--dry-run``
+compiles the full config's train step for the production mesh. Full
+width does not train on one chip: AdamW keeps ~16 bytes per parameter
+(~24 GB for Qwen2-1.5B against 16 GB on a TPU v5e), and the learner is
+not sharded. Serving runs at full width (``repro.launch.serve``).
 """
 
 from __future__ import annotations
@@ -40,6 +42,10 @@ def main() -> None:
         if args.multi_pod:
             cmd.append("--multi-pod")
         raise SystemExit(subprocess.call(cmd))
+
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     from repro.configs import get_config, smoke_variant
     from repro.core.drafter import DrafterConfig

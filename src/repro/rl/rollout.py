@@ -193,9 +193,10 @@ class MultiWorkerRollout:
     cannot tell it from a single-worker batch.
 
     With ``fault_tolerant=True`` a worker that stalls (``StallError``
-    from its watchdog), dies mid-slice, or loses its shards does not
-    sink the step: the worker is expired for this call and its slice
-    re-queues — with the slice's ORIGINAL sampling key — to a survivor,
+    from its watchdog), dies mid-slice, or loses its shards (``OSError``)
+    does not sink the step: the worker is expired for this call and its
+    slice re-queues — with the slice's ORIGINAL sampling key — to a
+    survivor,
     so at T=0 the merged batch is token-identical to the no-failure run
     (greedy verification makes outputs worker-independent; at T>0 the
     sampling stream is slice-bound, so determinism per slice holds
@@ -319,10 +320,12 @@ class MultiWorkerRollout:
                     collect_effective_batch=collect_effective_batch,
                     resume=salvage,
                 )
-            except (StallError, RuntimeError, OSError) as exc:
-                # StallError: watchdog expired the worker. RuntimeError/
-                # OSError: the worker's engine or its service connection
-                # died mid-slice.
+            except (StallError, OSError) as exc:
+                # StallError: the watchdog expired the worker (or it died
+                # at a journal commit). OSError: its history-service
+                # connection failed. Anything else — a JAX runtime error
+                # such as a device OOM among them — is a fault of the
+                # program, not of one worker: it propagates.
                 if not self.fault_tolerant:
                     raise
                 expired.add(w)

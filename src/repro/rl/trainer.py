@@ -209,6 +209,9 @@ class Trainer:
                 self.supervisor.start(tcfg.supervise_interval_s)
         self.engines = []
         self._clients = []
+        # One replica per device (round-robin): each worker's engine
+        # serves from its own chip, and set_params re-commits there.
+        devs = jax.devices()
         for w in range(tcfg.n_workers):
             client = HistoryClient(
                 # the service's live AddressBook: a supervisor restart
@@ -223,7 +226,8 @@ class Trainer:
             if self.telemetry.enabled:
                 client.attach_telemetry(self.telemetry)
             eng = SpecEngine(
-                self.params, cfg, tcfg.engine,
+                jax.device_put(self.params, devs[w % len(devs)]), cfg,
+                tcfg.engine,
                 drafter=SuffixDrafter(tcfg.drafter, remote=client),
                 length_policy=LengthPolicy(),
                 telemetry=self.telemetry,

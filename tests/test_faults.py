@@ -575,6 +575,37 @@ def test_flaky_worker_requeues_to_survivor_token_identical(tiny_dense):
         mw_dead.rollout(problems, key=jax.random.key(3))
 
 
+def test_device_runtime_error_propagates_instead_of_requeue(tiny_dense):
+    """A JAX runtime error (a device OOM, say) is a fault of the program,
+    not of one worker: fault-tolerant mode must not hand the slice to a
+    survivor, which would hit the same error or hide it."""
+    import jax
+
+    from conftest import make_params
+    from repro.data.tasks import PatternTask
+    from repro.rl.rollout import MultiWorkerRollout
+
+    class DeviceErrorWorker(FlakyWorker):
+        def rollout(self, *args, **kwargs):
+            self.calls += 1
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: injected device OOM"
+            )
+
+    params = make_params(tiny_dense)
+    task = PatternTask(n_problems=4, mean_len=6.0, max_len=10, seed=0)
+    failing = DeviceErrorWorker(_mk_worker(params, tiny_dense, task))
+    survivor = FlakyWorker(_mk_worker(params, tiny_dense, task),
+                           fail_calls=())
+    mw = MultiWorkerRollout([failing, survivor], fault_tolerant=True)
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        mw.rollout(task.problems(), key=jax.random.key(1))
+    assert failing.calls == 1
+    assert survivor.calls == 0
+    assert mw.stats["worker_failures"] == 0
+    assert mw.stats["requeued_problems"] == 0
+
+
 def test_watchdog_threads_through_engine_rounds(tiny_dense):
     import jax
 

@@ -226,3 +226,20 @@ def test_fused_strictly_fewer_transfers_per_round():
             stats.n_rounds, 1
         )
     assert per_round["on"] < per_round["off"], per_round
+
+
+def test_fused_rounds_draft_with_the_xla_core(monkeypatch):
+    """The main path drafts with the XLA scalar core on every backend:
+    serving with fused rounds and real proposals traces no Pallas
+    kernel (none can run interpreted on CPU, and none reaches the TPU
+    lowering that refuses them)."""
+    from jax.experimental import pallas as pl
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("the main path reached pallas_call")
+
+    monkeypatch.setattr(pl, "pallas_call", refuse)
+    eng = _engine(make_params(DENSE), DENSE, fuse="auto")
+    _, _, st = _two_epochs(eng, mode="continuous")
+    assert st.n_drafted > 0
+    assert eng._fused_jit and not eng._verify_jit

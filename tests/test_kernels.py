@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode), with hypothesis
+"""Pallas kernels vs pure-jnp oracles, run through the Pallas interpreter
+(each call asks for it: the wrappers compile by default), with hypothesis
 shape/dtype sweeps."""
 
 import jax
@@ -39,7 +40,9 @@ def _run_case(B, T, Hq, Hkv, hd, S, window, softcap, dtype, seed=0):
         q, k, v, jnp.asarray(cpos, jnp.int32),
         jnp.asarray(positions, jnp.int32),
     )
-    out = spec_verify_attention(*args, window=window, softcap=softcap, chunk=128)
+    out = spec_verify_attention(
+        *args, window=window, softcap=softcap, chunk=128, interpret=True
+    )
     ref = spec_verify_attention_ref(*args, window=window, softcap=softcap)
     atol = 3e-2 if dtype == "bfloat16" else 3e-5
     np.testing.assert_allclose(
@@ -86,7 +89,7 @@ def test_rglru_kernel_cases(B, T, W):
     i = jnp.asarray(rng.uniform(size=(B, T, W)), jnp.float32)
     lam = jnp.asarray(rng.normal(size=(W,)), jnp.float32)
     h0 = jnp.asarray(rng.normal(size=(B, W)), jnp.float32)
-    hs, hf = rglru_scan(x, r, i, lam, h0)
+    hs, hf = rglru_scan(x, r, i, lam, h0, interpret=True)
     hs_r, hf_r = rglru_scan_ref(x, r, i, lam, h0)
     np.testing.assert_allclose(np.asarray(hs), np.asarray(hs_r), atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(hf), np.asarray(hf_r), atol=1e-5, rtol=1e-5)
@@ -103,14 +106,18 @@ def test_rglru_kernel_hypothesis(B, T, W):
     i = jnp.asarray(rng.uniform(size=(B, T, W)), jnp.float32)
     lam = jnp.asarray(rng.normal(size=(W,)), jnp.float32)
     h0 = jnp.asarray(rng.normal(size=(B, W)), jnp.float32)
-    hs, hf = rglru_scan(x, r, i, lam, h0)
+    hs, hf = rglru_scan(x, r, i, lam, h0, interpret=True)
     hs_r, hf_r = rglru_scan_ref(x, r, i, lam, h0)
     np.testing.assert_allclose(np.asarray(hs), np.asarray(hs_r), atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(hf), np.asarray(hf_r), atol=1e-5, rtol=1e-5)
 
 
 def test_kernel_matches_model_attention_layer():
-    """attention_forward(attn_impl='pallas') must agree with the XLA path."""
+    """attention_forward(attn_impl='pallas') must agree with the XLA path.
+    The model layer calls the kernel compiled; on CPU the test asks for
+    the TPU interpreter around the call."""
+    from jax.experimental.pallas import tpu as pltpu
+
     from conftest import make_params
     from repro.configs.base import ModelConfig
     from repro.models import model as M
@@ -127,9 +134,46 @@ def test_kernel_matches_model_attention_layer():
     block = jax.random.randint(jax.random.key(2), (B, 4), 0, cfg.vocab_size)
     outs = {}
     for impl in ("xla", "pallas"):
-        logits, _, _ = M.forward(
-            params, cfg, block, cache=cache, valid=jnp.ones((B, 4), bool),
-            commit_upto=jnp.zeros((B,), jnp.int32), attn_impl=impl,
-        )
+        with pltpu.force_tpu_interpret_mode():
+            logits, _, _ = M.forward(
+                params, cfg, block, cache=cache,
+                valid=jnp.ones((B, 4), bool),
+                commit_upto=jnp.zeros((B,), jnp.int32), attn_impl=impl,
+            )
         outs[impl] = np.asarray(logits)
     np.testing.assert_allclose(outs["xla"], outs["pallas"], atol=3e-4, rtol=1e-3)
+
+
+def _suffix_match_pallas(interpret):
+    from repro.kernels.suffix_match.ops import pack_forest, suffix_match_propose
+
+    forest, _ = pack_forest([])
+    return suffix_match_propose(
+        forest, np.full((2, 8), -1, np.int32), np.full(2, -1, np.int32),
+        np.zeros(2, np.int32), n_prop_max=4, impl="pallas",
+        interpret=interpret,
+    )
+
+
+def _rglru(interpret):
+    x = jnp.ones((1, 8, 128), jnp.float32)
+    return rglru_scan(x, 0.5 * x, 0.5 * x, jnp.ones(128), jnp.zeros((1, 128)),
+                      interpret=interpret)
+
+
+def _spec_verify(interpret):
+    q = jnp.ones((1, 2, 4, 64), jnp.float32)
+    kv = jnp.ones((1, 128, 2, 64), jnp.float32)
+    cpos = jnp.arange(128, dtype=jnp.int32)[None]
+    return spec_verify_attention(q, kv, kv, cpos, jnp.full((1, 2), 128),
+                                 chunk=128, interpret=interpret)
+
+
+@pytest.mark.parametrize("call", [_suffix_match_pallas, _rglru, _spec_verify])
+def test_kernels_compile_unless_interpret_is_asked(call):
+    """No wrapper falls back to the interpreter by itself: on CPU the
+    default (compiled) call is refused, and only an explicit
+    ``interpret=True`` runs. On a TPU the same default compiles."""
+    jax.block_until_ready(call(interpret=True))
+    with pytest.raises(ValueError, match="interpret mode"):
+        jax.block_until_ready(call(interpret=False))

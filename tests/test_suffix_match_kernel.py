@@ -55,6 +55,7 @@ def _device(trees, ctxs, budgets, min_match, impl="ref", roots_neg=()):
     ml, npr, props = suffix_match_propose(
         forest, tails, roots, np.asarray(budgets, np.int32),
         n_prop_max=KMAX, min_match=min_match, impl=impl,
+        interpret=impl == "pallas",
     )
     ml, npr, props = np.asarray(ml), np.asarray(npr), np.asarray(props)
     return ml, [props[b, : npr[b]].tolist() for b in range(n)]
@@ -233,6 +234,7 @@ def _device_chunked(trees, ctxs, budgets, min_match, impl="ref"):
     ml, npr, props = suffix_match_propose(
         forest, tails, roots, np.asarray(budgets, np.int32),
         n_prop_max=KMAX, min_match=min_match, impl=impl,
+        interpret=impl == "pallas",
     )
     ml, npr, props = np.asarray(ml), np.asarray(npr), np.asarray(props)
     return ml, [props[b, : npr[b]].tolist() for b in range(n)]
