@@ -95,22 +95,24 @@ def verify_step(
     # trick; recurrent layers emit staged per-step states
     # (collect_states) that are gathered at the acceptance count below —
     # no second forward.
-    logits, cache1, _ = M.forward(
-        params, cfg, block, cache=cache, valid=valid,
-        commit_upto=None if recurrent else jnp.zeros((B,), jnp.int32),
-        attn_impl=attn_impl, collect_states=recurrent,
-    )
-    logits = logits[:, :, : cfg.vocab_size]
-    res = verify_block(
-        logits, block, budgets, temperature=temperature, key=key,
-        active=active,
-    )
-    n_commit = jnp.where(active, 1 + res.accepted, 0)
-    if recurrent:
-        cache1 = M.commit_staged_cache(cfg, cache1, n_commit)
-    cache1 = cache1._replace(
-        lengths=cache1.lengths + n_commit.astype(jnp.int32)
-    )
+    with jax.named_scope("forward"):
+        logits, cache1, _ = M.forward(
+            params, cfg, block, cache=cache, valid=valid,
+            commit_upto=None if recurrent else jnp.zeros((B,), jnp.int32),
+            attn_impl=attn_impl, collect_states=recurrent,
+        )
+    with jax.named_scope("accept"):
+        res = verify_block(
+            logits[:, :, : cfg.vocab_size], block, budgets,
+            temperature=temperature, key=key, active=active,
+        )
+    with jax.named_scope("commit"):
+        n_commit = jnp.where(active, 1 + res.accepted, 0)
+        if recurrent:
+            cache1 = M.commit_staged_cache(cfg, cache1, n_commit)
+        cache1 = cache1._replace(
+            lengths=cache1.lengths + n_commit.astype(jnp.int32)
+        )
     return res, cache1
 
 
@@ -151,61 +153,74 @@ def fused_round_core(
     ``[cand (K+1) | accepted | n_take | alive | n_prop]``. Rows outside
     ``state.active`` carry zeros in the bookkeeping columns and leave
     cache/state untouched.
+
+    The round's phases run under ``jax.named_scope``: ``propose`` (draft
+    walk and block build), ``forward`` (layer scan and cache ring write),
+    ``accept`` (acceptance, candidates, emit scan) and ``commit``
+    (cache lengths, tail shift register, next state, output pack). The
+    scopes reach every HLO instruction's ``op_name`` metadata, so a
+    device trace splits the round's time by phase; they change no
+    computation.
     """
     B, m = state.tails.shape
     i32 = jnp.int32
-    if K > 0:
-        # Rows without a packed tree (root < 0) or without budget propose
-        # nothing and take a plain AR step — same as the unfused path.
-        proots = jnp.where(state.active & (budgets > 0), roots, -1)
-        _, n_prop, props = sm_ops.propose_device(
-            forest, state.tails, proots, budgets,
-            n_prop_max=K, min_match=min_match,
-        )
-        n_prop = n_prop.astype(i32)
-        drafts = jnp.where(
-            jnp.arange(K)[None, :] < n_prop[:, None], props, 0
-        ).astype(i32)
-    else:
-        n_prop = jnp.zeros((B,), i32)
-        drafts = jnp.zeros((B, 0), i32)
-    block = jnp.concatenate([state.head[:, None], drafts], axis=1)
+    with jax.named_scope("propose"):
+        if K > 0:
+            # Rows without a packed tree (root < 0) or without budget
+            # propose nothing and take a plain AR step — same as the
+            # unfused path.
+            proots = jnp.where(state.active & (budgets > 0), roots, -1)
+            _, n_prop, props = sm_ops.propose_device(
+                forest, state.tails, proots, budgets,
+                n_prop_max=K, min_match=min_match,
+            )
+            n_prop = n_prop.astype(i32)
+            drafts = jnp.where(
+                jnp.arange(K)[None, :] < n_prop[:, None], props, 0
+            ).astype(i32)
+        else:
+            n_prop = jnp.zeros((B,), i32)
+            drafts = jnp.zeros((B, 0), i32)
+        block = jnp.concatenate([state.head[:, None], drafts], axis=1)
     res, cache = verify_step(
         params, cfg, cache, block, n_prop, state.active, key,
         temperature=temperature, recurrent=recurrent, attn_impl=attn_impl,
     )
-    accepted = res.accepted.astype(i32)
-    next_tok = res.next_token.astype(i32)
-    cand = jnp.concatenate([block[:, 1:], jnp.zeros((B, 1), i32)], axis=1)
-    cand = cand.at[jnp.arange(B), accepted].set(next_tok)
-    n_take, alive = emit_scan_device(
-        cand, accepted + 1, state.max_new - state.emitted, eos_token
-    )
-    alive = alive & state.active
-    n_take_eff = jnp.where(state.active, n_take, 0).astype(i32)
-    # Context-tail shift register: the last m of (tail ++ taken tokens).
-    # The gather window ends exactly at the last taken token, so junk
-    # cand positions past n_take never enter the tail.
-    comb = jnp.concatenate([state.tails, cand], axis=1)
-    idx = n_take_eff[:, None] + jnp.arange(m)[None, :]
-    fed_tails = jnp.take_along_axis(comb, idx, axis=1)
-    state2 = RoundState(
-        head=jnp.where(alive, next_tok, state.head),
-        tails=jnp.where(alive[:, None], fed_tails, state.tails),
-        active=alive,
-        emitted=state.emitted + n_take_eff,
-        max_new=state.max_new,
-    )
-    out = jnp.concatenate(
-        [
-            cand,
-            accepted[:, None],
-            n_take_eff[:, None],
-            alive.astype(i32)[:, None],
-            jnp.where(state.active, n_prop, 0)[:, None],
-        ],
-        axis=1,
-    )
+    with jax.named_scope("accept"):
+        accepted = res.accepted.astype(i32)
+        next_tok = res.next_token.astype(i32)
+        cand = jnp.concatenate([block[:, 1:], jnp.zeros((B, 1), i32)],
+                               axis=1)
+        cand = cand.at[jnp.arange(B), accepted].set(next_tok)
+        n_take, alive = emit_scan_device(
+            cand, accepted + 1, state.max_new - state.emitted, eos_token
+        )
+        alive = alive & state.active
+        n_take_eff = jnp.where(state.active, n_take, 0).astype(i32)
+    with jax.named_scope("commit"):
+        # Context-tail shift register: the last m of (tail ++ taken
+        # tokens). The gather window ends exactly at the last taken
+        # token, so junk cand positions past n_take never enter the tail.
+        comb = jnp.concatenate([state.tails, cand], axis=1)
+        idx = n_take_eff[:, None] + jnp.arange(m)[None, :]
+        fed_tails = jnp.take_along_axis(comb, idx, axis=1)
+        state2 = RoundState(
+            head=jnp.where(alive, next_tok, state.head),
+            tails=jnp.where(alive[:, None], fed_tails, state.tails),
+            active=alive,
+            emitted=state.emitted + n_take_eff,
+            max_new=state.max_new,
+        )
+        out = jnp.concatenate(
+            [
+                cand,
+                accepted[:, None],
+                n_take_eff[:, None],
+                alive.astype(i32)[:, None],
+                jnp.where(state.active, n_prop, 0)[:, None],
+            ],
+            axis=1,
+        )
     return cache, state2, out
 
 

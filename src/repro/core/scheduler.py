@@ -105,6 +105,7 @@ class Request:
     output: List[int] = field(default_factory=list)  # EOS-stripped on finish
     emitted: int = 0
     rounds: int = 0  # verify rounds while resident
+    submit_round: int = -1  # pool round at (most recent) submission
     admit_round: int = -1  # pool round at (most recent) admission
     finish_round: int = -1
     session: Any = None  # drafter DraftSession while RUNNING

@@ -309,6 +309,13 @@ class SpecEngine:
                      "Device-to-host array crossings"),
             "round_host": h("das_round_host_seconds",
                             "Host bookkeeping time per round dispatch"),
+            "forest_upload": c("das_forest_upload_bytes_total",
+                               "Bytes of packed suffix forest and row "
+                               "roots uploaded to the device"),
+            "queue_wait": h("das_queue_wait_rounds",
+                            "Rounds from a request's submission to its "
+                            "admission into a slot",
+                            buckets=obs.exp_buckets(1.0, 2.0, 12)),
             "resumed": c("das_resumed_tokens_total",
                          "Tokens salvaged into resumed rollouts (journal "
                          "recovery / preemption re-admission)"),
@@ -1112,6 +1119,7 @@ class SpecEngine:
                     resume=bool(r.resume_tokens), trace=r.trace,
                 )
         for r in reqs:
+            r.submit_round = 0
             sched.submit(r)
             if rec_flight:
                 flt.record(r.trace, "queued", rid=r.rid)
@@ -1234,6 +1242,10 @@ class SpecEngine:
                 stats.n_d2h += 1
             prefill_s = time.perf_counter() - tp0
             stats.n_fwd += 1
+            if tel_obs.enabled:
+                self._mx["queue_wait"].observe_many(
+                    round_no - req.submit_round for req, _ in sub
+                )
             stats.n_toks_proposed += int(
                 sum(len(c) for _, c in sub)
             )
@@ -1524,6 +1536,7 @@ class SpecEngine:
             req.head = -1
             req.predicted_len = sched.remaining_len(req)
             if requeue:
+                req.submit_round = round_no
                 sched.submit(req)
             self._preempt_fam.labels(reason).inc()
             if rec_flight:
@@ -1649,10 +1662,15 @@ class SpecEngine:
                 bds.prewarm()
                 last_ver = bds.repack_version
                 roots_dirty = False
+                up = 0
                 if bds.forest_arrays() is not forest_src:
                     forest_src = bds.forest_arrays()
                     forest = self._to_device(forest_src)
-                roots_dev = self._to_device(bds.roots_array())
+                    up = sum(a.nbytes for a in forest_src)
+                roots = bds.roots_array()
+                roots_dev = self._to_device(roots)
+                if tel_obs.enabled:
+                    self._mx["forest_upload"].inc(float(up + roots.nbytes))
                 stats.n_h2d += 1
                 sp_s.set(h2d=1)
 

@@ -243,3 +243,56 @@ def test_fused_rounds_draft_with_the_xla_core(monkeypatch):
     _, _, st = _two_epochs(eng, mode="continuous")
     assert st.n_drafted > 0
     assert eng._fused_jit and not eng._verify_jit
+
+
+PHASES = ("propose", "forward", "accept", "commit")
+
+
+def _scoped_instructions(hlo_text):
+    """(instruction name, first phase scope or None) of every HLO
+    instruction that carries ``op_name`` metadata."""
+    import re
+
+    out = []
+    for name, op in re.findall(
+        r'^\s*(?:ROOT )?%(\S+) = [^\n]*?metadata=\{op_name="([^"]*)"',
+        hlo_text, re.M,
+    ):
+        parts = op.split("/")
+        out.append((name, next((p for p in parts if p in PHASES), None)))
+    return out
+
+
+@pytest.mark.parametrize("program", ["fused", "verify"])
+def test_round_phases_are_named_scopes(program):
+    """The compiled round names its phases in every instruction's
+    ``op_name``: the fused program all four, the unfused verify (the
+    same ``verify_step``) forward, accept and commit. The layer scan's
+    ``while`` falls under ``forward``."""
+    from repro.core.fused_round import build_fused_round, make_state
+    from repro.kernels.suffix_match import ops as sm_ops
+    from repro.models import model as M
+
+    params = make_params(DENSE)
+    B, K = 4, 4
+    cache = M.init_cache(DENSE, B, 64, 0)
+    key = jax.random.key(0)
+    if program == "fused":
+        fn = build_fused_round(
+            DENSE, K=K, micro_rounds=1, temperature=0.0, eos_token=1,
+            recurrent=False, attn_impl="xla", min_match=1,
+        )
+        forest, _ = sm_ops.pack_forest([])
+        state = make_state(np.zeros(B), np.full((B, 8), -1),
+                           np.zeros(B, bool), np.zeros(B), np.ones(B))
+        args = (params, forest, cache, state, np.full(B, -1, np.int32),
+                np.zeros(B, np.int32), key)
+        want = set(PHASES)
+    else:
+        fn = _engine(params, DENSE, fuse="off")._get_verify(K)
+        args = (params, cache, np.zeros((B, K + 1), np.int32),
+                np.zeros(B, np.int32), np.ones(B, bool), key)
+        want = {"forward", "accept", "commit"}
+    scoped = _scoped_instructions(fn.lower(*args).compile().as_text())
+    assert {s for _, s in scoped} - {None} == want
+    assert any(n.startswith("while") and s == "forward" for n, s in scoped)

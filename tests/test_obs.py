@@ -312,6 +312,79 @@ def test_serve_span_hierarchy_and_metrics():
     ) - h2d_before == float(stats.n_h2d)
 
 
+def _warm_fused_engine(tel):
+    """A fused engine whose epoch 0 (lock-step) filled the drafter's
+    history, ready for epoch 1."""
+    eng = _engine(make_params(DENSE), fuse="on", telemetry=tel)
+    eng.begin_iteration(0)
+    eng.generate(PROMPTS, PIDS, key=jax.random.key(5))
+    eng.begin_iteration(1)
+    return eng
+
+
+def _serve_all(eng, slots=2):
+    """Serve every prompt through the continuous ``serve``; returns the
+    requests."""
+    reqs = [
+        Request(rid=i, problem_id=PIDS[i], prompt=list(PROMPTS[i]),
+                max_new_tokens=12)
+        for i in range(len(PROMPTS))
+    ]
+    assert len(list(eng.serve(reqs, slots=slots,
+                              key=jax.random.key(3)))) == len(reqs)
+    return reqs
+
+
+def test_spans_appear_in_a_jax_profile(tmp_path):
+    """A real Telemetry's spans are host events of any JAX profile, on
+    the device trace's clock."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    eng = _warm_fused_engine(obs.Telemetry())
+    with jax.profiler.trace(str(tmp_path)):
+        _serve_all(eng)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert {"serve_round", "consume", "history_publish"} <= names
+
+
+def test_forest_upload_bytes_and_queue_wait(monkeypatch):
+    """``das_forest_upload_bytes_total`` is the bytes of the forests and
+    root vectors the serve loop uploads; ``das_queue_wait_rounds`` holds
+    one observation per admission, its rounds in the queue."""
+    from repro.kernels.suffix_match.ops import PackedForest
+
+    tel = obs.Telemetry()
+    eng = _warm_fused_engine(tel)
+    uploaded = []
+    orig = SpecEngine._to_device
+
+    def spy(self, tree):
+        # a packed forest, or a host roots vector (the pool's cache and
+        # round state are uploaded once, as other types)
+        if isinstance(tree, (PackedForest, np.ndarray)):
+            uploaded.append(sum(x.nbytes for x in jax.tree.leaves(tree)))
+        return orig(self, tree)
+
+    monkeypatch.setattr(SpecEngine, "_to_device", spy)
+    up0 = tel.registry.value("das_forest_upload_bytes_total")
+    reqs = _serve_all(eng)
+    assert len(uploaded) >= 2  # the startup sync, then re-syncs
+    assert tel.registry.value("das_forest_upload_bytes_total") - up0 == (
+        float(sum(uploaded)))
+    wait = tel.registry.get("das_queue_wait_rounds")
+    assert wait.count == len(reqs)
+    assert wait.sum == sum(r.admit_round for r in reqs) > 0
+
+
 def test_metrics_server_live_serve():
     params = make_params(DENSE)
     tel = obs.Telemetry()
