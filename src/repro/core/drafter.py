@@ -55,15 +55,18 @@ class DrafterConfig:
     adapt_window_to_updates: bool = False
     window_gamma: float = 1.0
     min_window: int = 4
-    # Context-tail length fed to the device matcher (batched sessions):
-    # the usable match depth is capped at this many tokens. Chosen to
-    # equal MatchState's resync_cap, which imposes the same cap on host
+    # Context-tail length of the device matcher (batched sessions): the
+    # usable match depth is capped at this many tokens. Chosen to equal
+    # MatchState's resync_cap, which imposes the same cap on host
     # sessions whenever the tree mutated since their last round — the
     # continuous-serving regime. In mutation-free stretches (lock-step
     # generate within one batch) a persistent host session could hold
     # matches deeper than the tail; the device path deliberately trades
     # that tail-risk depth for bounded per-round state (acceptance-only
-    # effect — T=0 verification is lossless either way).
+    # effect — T=0 verification is lossless either way). The unfused
+    # propose feeds the whole tail every round; the fused round carries
+    # the matcher's registers and feeds only the new tokens, holding the
+    # match to the same cap.
     device_tail: int = 64
     # Packed-forest device layout. "flat" concatenates every tree into
     # one node table + corpus; "chunked" packs per-tree rows (the layout

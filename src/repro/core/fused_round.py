@@ -20,11 +20,17 @@ per (K-bucket, forest geometry):
 
 The per-row session state (``RoundState``) lives on device between
 rounds: heads are verify outputs, context tails are shift-registers
-updated from the accepted tokens, and the matcher re-derives its match
-registers from the resident tail exactly like the unfused device path
-(same ``match_propose_row`` core, same tail cap), so proposals — and
-therefore sampled tokens under a shared PRNG stream — are bit-identical
-to the unfused round.
+updated from the accepted tokens, and the matcher's registers
+(``MatchRegs``) persist too. A round feeds each row only the tail
+tokens the rounds since its last feed appended (``feed_from`` on), from
+the registers that feed left; resumed this way, the same
+``match_propose_row`` core under the same tail cap reaches the
+registers a feed of the whole tail from the root reaches, so proposals
+— and therefore sampled tokens under a shared PRNG stream — are
+bit-identical to the unfused round. Registers are only good against the
+tree they were fed in: the engine resets them (``forget_matches``)
+whenever it uploads a new forest or new roots, and for the rows it
+admits or evicts; a reset row is fed its whole tail from the root.
 
 The host uploads one (B,) budget vector per round and downloads one
 packed (B, K+5) result: ``[cand tokens | accepted | n_take | alive |
@@ -48,6 +54,7 @@ import numpy as np
 
 from repro.core.verify import verify_block
 from repro.kernels.suffix_match import ops as sm_ops
+from repro.kernels.suffix_match.kernel import MatchRegs
 from repro.models import model as M
 
 
@@ -59,19 +66,74 @@ class RoundState(NamedTuple):
     active: jnp.ndarray  # (B,) bool
     emitted: jnp.ndarray  # (B,) i32 tokens emitted so far
     max_new: jnp.ndarray  # (B,) i32 per-row token limit
+    # (B,) i32 each: the matcher's registers for the row's context before
+    # tails[:, feed_from], in the row's tree of the current forest. A row
+    # is carried while its node is >= 0; node -1 = not carried (the next
+    # feed starts at the root, from tail index feed_from).
+    match: MatchRegs
+    # (B,) i32 first tail index the row's next feed takes: for a row not
+    # carried 0, or m while its tree is not uploaded yet (admitted since
+    # the last sync: fed only once it has a budget).
+    feed_from: jnp.ndarray
+
+
+def _root_regs(n: int) -> MatchRegs:
+    """``n`` rows of registers that are not carried (at the root)."""
+    return MatchRegs(*(jnp.full((n,), v, jnp.int32) for v in (-1, -1, 0, 0)))
 
 
 def make_state(head, tails, active, emitted, max_new) -> RoundState:
     """Build a device ``RoundState`` from host arrays (one-time upload
     at pool/batch construction; afterwards the state only lives on
-    device)."""
+    device). No row's matcher is carried: the first round feeds whole
+    tails."""
+    B = np.shape(head)[0]
     return RoundState(
         head=jnp.asarray(np.asarray(head, np.int32)),
         tails=jnp.asarray(np.asarray(tails, np.int32)),
         active=jnp.asarray(np.asarray(active, bool)),
         emitted=jnp.asarray(np.asarray(emitted, np.int32)),
         max_new=jnp.asarray(np.asarray(max_new, np.int32)),
+        match=_root_regs(B),
+        feed_from=jnp.zeros((B,), jnp.int32),
     )
+
+
+def forget_matches(state: RoundState, slots=None) -> RoundState:
+    """Reset matchers to not carried: every row's (``slots=None``: after
+    a new forest or new roots), or the given rows' (eviction). ``slots``
+    may hold out-of-range pads, which drop."""
+    B = state.feed_from.shape[0]
+    if slots is None:
+        return state._replace(match=_root_regs(B),
+                              feed_from=jnp.zeros((B,), jnp.int32))
+    return state._replace(
+        match=MatchRegs(*(r.at[slots].set(v)
+                          for r, v in zip(state.match, (-1, -1, 0, 0)))),
+        feed_from=state.feed_from.at[slots].set(0),
+    )
+
+
+def matcher_feeds(active, has_tree, budgets, carried, feed_from, m):
+    """Rows a fused round with K > 0 feeds: active rows with a tree,
+    budget or not, except rows still waiting for their tree's upload
+    (``feed_from == m``, not carried) that have no budget. Takes jnp
+    arrays on device, numpy ones for the host's mirror of the state."""
+    waiting = ~carried & (feed_from >= m)
+    return active & has_tree & ~(waiting & (budgets <= 0))
+
+
+def advance_feed(xp, fed, carried, feed_from, alive, n_take, m):
+    """(carried, feed_from) after a round that appended ``n_take`` tokens
+    to every row's tail: fed rows resume after the tail's end, unfed
+    carried rows where they were; both shift left with the tail, and a
+    row stays carried while it lives and its resume index stays in the
+    tail. Rows dropped keep waiting if they waited, else restart at 0.
+    ``xp`` is ``jnp`` on device, ``np`` for the host's mirror."""
+    nxt = xp.where(fed, m, feed_from) - n_take
+    keep = (fed | carried) & alive & (nxt >= 0)
+    waiting = ~fed & ~carried & (feed_from >= m)
+    return keep, xp.where(keep, nxt, xp.where(waiting, m, 0))
 
 
 # Packed per-round result columns appended after the K+1 cand tokens.
@@ -164,21 +226,30 @@ def fused_round_core(
     """
     B, m = state.tails.shape
     i32 = jnp.int32
+    carried = state.match.node >= 0
     with jax.named_scope("propose"):
         if K > 0:
+            # Feed rows from their carried registers (the tokens since
+            # their last feed) or, not carried, their whole tail from the
+            # root; rows without budget are fed too, to stay carried.
             # Rows without a packed tree (root < 0) or without budget
             # propose nothing and take a plain AR step — same as the
             # unfused path.
-            proots = jnp.where(state.active & (budgets > 0), roots, -1)
-            _, n_prop, props = sm_ops.propose_device(
-                forest, state.tails, proots, budgets,
+            fed = matcher_feeds(state.active, roots >= 0, budgets,
+                                carried, state.feed_from, m)
+            first = jnp.where(carried, state.feed_from, 0)
+            _, n_prop, props, regs = sm_ops.propose_device(
+                forest, state.tails, jnp.where(fed, roots, -1), budgets,
                 n_prop_max=K, min_match=min_match,
+                start=(state.match, first),
             )
             n_prop = n_prop.astype(i32)
             drafts = jnp.where(
                 jnp.arange(K)[None, :] < n_prop[:, None], props, 0
             ).astype(i32)
         else:
+            fed = jnp.zeros((B,), bool)
+            regs = state.match
             n_prop = jnp.zeros((B,), i32)
             drafts = jnp.zeros((B, 0), i32)
         block = jnp.concatenate([state.head[:, None], drafts], axis=1)
@@ -204,12 +275,17 @@ def fused_round_core(
         comb = jnp.concatenate([state.tails, cand], axis=1)
         idx = n_take_eff[:, None] + jnp.arange(m)[None, :]
         fed_tails = jnp.take_along_axis(comb, idx, axis=1)
+        keep, feed_from = advance_feed(jnp, fed, carried, state.feed_from,
+                                       alive, n_take_eff, m)
         state2 = RoundState(
             head=jnp.where(alive, next_tok, state.head),
             tails=jnp.where(alive[:, None], fed_tails, state.tails),
             active=alive,
             emitted=state.emitted + n_take_eff,
             max_new=state.max_new,
+            match=MatchRegs(*(jnp.where(keep, r, z) for r, z in
+                              zip(regs, _root_regs(B)))),
+            feed_from=feed_from,
         )
         out = jnp.concatenate(
             [

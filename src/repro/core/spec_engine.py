@@ -61,9 +61,11 @@ from repro.configs.base import ModelConfig
 from repro.core.budget import LatencyModel, solve_budgets
 from repro.core.drafter import DrafterConfig, SuffixDrafter
 from repro.core.fused_round import (
-    RoundState,
+    advance_feed,
     build_fused_round,
+    forget_matches,
     make_state,
+    matcher_feeds,
     unpack_round_out,
     verify_step,
 )
@@ -230,6 +232,64 @@ def _as_max_new_array(mn, B: int) -> np.ndarray:
     return np.full(B, int(mn), np.int64)
 
 
+class _MatcherMirror:
+    """The host's copy of the fused rounds' matcher state: which rows are
+    carried and where each row's next feed starts. It follows the
+    device's ``RoundState`` through the same update rules
+    (``matcher_feeds``, ``advance_feed``) from the engine's own sync,
+    admission and eviction bookkeeping and each round's downloaded
+    result, so counting how rows are fed costs no download."""
+
+    def __init__(self, n_rows: int, m: int, rows_carried, rows_full,
+                 full_rounds) -> None:
+        self.m = m
+        self.carried = np.zeros(n_rows, bool)
+        self.feed_from = np.zeros(n_rows, np.int64)
+        self._forget_after = False
+        self._counters = (rows_carried, rows_full, full_rounds)
+
+    def feeds(self, K: int, active, roots, budgets) -> np.ndarray:
+        """Rows a round dispatched now feeds; counts them by how."""
+        if K == 0:
+            return np.zeros_like(self.carried)
+        fed = matcher_feeds(active, roots >= 0, budgets, self.carried,
+                            self.feed_from, self.m)
+        n_carried = int((fed & self.carried).sum())
+        n_full = int(fed.sum()) - n_carried
+        rows_carried, rows_full, full_rounds = self._counters
+        rows_carried.inc(float(n_carried))
+        rows_full.inc(float(n_full))
+        if n_full:
+            full_rounds.inc()
+        return fed
+
+    def advance(self, fed, alive, n_take) -> None:
+        """Apply a consumed round (and a reset queued behind it)."""
+        self.carried, self.feed_from = advance_feed(
+            np, fed, self.carried, self.feed_from, alive, n_take, self.m
+        )
+        if self._forget_after:
+            self._forget_after = False
+            self.forget()
+
+    def forget(self, rows=None, *, in_flight: bool = False) -> None:
+        """``forget_matches`` on the device: every row, or ``rows``. With
+        a round in flight the reset lands after it (``advance``)."""
+        if in_flight:
+            self._forget_after = True
+        elif rows is None:
+            self.carried[:] = False
+            self.feed_from[:] = 0
+        else:
+            self.carried[rows] = False
+            self.feed_from[rows] = 0
+
+    def admit(self, rows) -> None:
+        """Admitted rows wait for their tree's upload."""
+        self.carried[rows] = False
+        self.feed_from[rows] = self.m
+
+
 class SpecEngine:
     """Speculative rollout engine: draft (host) → verify (device)."""
 
@@ -266,6 +326,7 @@ class SpecEngine:
         self._copy_rows_fn = None
         self._admit_state_fn = None
         self._evict_state_fn = None
+        self._forget_fn = None
         # Per-(problem, partial-length) budget memo: with G samples per
         # problem the per-row LengthPolicy calls are G-way duplicated
         # every verify round; keyed on the history version so any new
@@ -324,6 +385,18 @@ class SpecEngine:
             "das_preemptions_total",
             "Resident rollouts evicted from their slot, by reason",
             ("reason",),
+        )
+        feed_fam = tel.registry.counter_family(
+            "das_matcher_rows_total",
+            "Rows the fused round's draft matcher fed, by how: from "
+            "carried registers (the new tokens) or in full (the whole "
+            "tail from the root)",
+            ("feed",),
+        )
+        self._matcher_counters = (
+            feed_fam.labels("carried"), feed_fam.labels("full"),
+            c("das_matcher_full_rounds_total",
+              "Fused rounds in which any row was fed in full"),
         )
         fam = tel.registry.histogram_family(
             "das_accepted_tokens",
@@ -479,16 +552,19 @@ class SpecEngine:
         """Jitted fused-state admission write: newly admitted rows'
         head/tail/limit/emitted scatter into the device ``RoundState``
         (``emitted`` is 1 for fresh admissions, the salvaged length for
-        journal/preemption resumes). ``slots`` may be padded with
-        ``n_slots`` (out-of-range scatters drop)."""
+        journal/preemption resumes). Their matchers reset and wait for
+        the next sync to upload their trees' roots. ``slots`` may be
+        padded with ``n_slots`` (out-of-range scatters drop)."""
         if self._admit_state_fn is None:
             def write_fn(state, slots, heads, tails, max_new, emitted):
-                return RoundState(
+                state = forget_matches(state, slots)
+                return state._replace(
                     head=state.head.at[slots].set(heads),
                     tails=state.tails.at[slots].set(tails),
                     active=state.active.at[slots].set(True),
                     emitted=state.emitted.at[slots].set(emitted),
                     max_new=state.max_new.at[slots].set(max_new),
+                    feed_from=state.feed_from.at[slots].set(tails.shape[1]),
                 )
 
             self._admit_state_fn = jax.jit(write_fn, donate_argnums=(0,))
@@ -498,20 +574,26 @@ class SpecEngine:
         """Jitted fused-state eviction write: preempted / cancelled /
         expired rows' ``active`` bits clear in one donated scatter (the
         other columns are dead once inactive — the next admission into
-        the slot overwrites them). ``slots`` may be padded with
-        ``n_slots`` (out-of-range scatters drop)."""
+        the slot overwrites them) and their matchers reset. ``slots``
+        may be padded with ``n_slots`` (out-of-range scatters drop)."""
         if self._evict_state_fn is None:
             def evict_fn(state, slots):
-                return RoundState(
-                    head=state.head,
-                    tails=state.tails,
-                    active=state.active.at[slots].set(False),
-                    emitted=state.emitted,
-                    max_new=state.max_new,
+                state = forget_matches(state, slots)
+                return state._replace(
+                    active=state.active.at[slots].set(False)
                 )
 
             self._evict_state_fn = jax.jit(evict_fn, donate_argnums=(0,))
         return self._evict_state_fn
+
+    def _get_forget_matches(self):
+        """Jitted donated reset of every row's carried matcher, enqueued
+        behind the round in flight whenever a new forest or new roots
+        are uploaded (registers name nodes of the forest they were fed
+        in)."""
+        if self._forget_fn is None:
+            self._forget_fn = jax.jit(forget_matches, donate_argnums=(0,))
+        return self._forget_fn
 
     def compile_count(self) -> int:
         """Total jit compilations attributable to this engine (plus the
@@ -527,7 +609,7 @@ class SpecEngine:
             + list(self._fused_jit.values())
         )
         for f in (self._copy_rows_fn, self._admit_state_fn,
-                  self._evict_state_fn):
+                  self._evict_state_fn, self._forget_fn):
             if f is not None:
                 fns.append(f)
         fns += [sm_ops._dispatch, sm_ref.suffix_match_propose_ref]
@@ -914,8 +996,10 @@ class SpecEngine:
             head, bds.tails_matrix(), active, emitted, max_new_arr
         ))
         stats.n_h2d += 5
+        mirror = _MatcherMirror(B, bds.tail_len, *self._matcher_counters)
         forest = self._to_device(bds.forest_arrays())
-        roots_dev = self._to_device(bds.roots_array())
+        roots = bds.roots_array()
+        roots_dev = self._to_device(roots)
         stats.n_h2d += 1
         last_ver = bds.repack_version
         while active.any():
@@ -936,8 +1020,11 @@ class SpecEngine:
                     if bds.repack_version != last_ver:
                         last_ver = bds.repack_version
                         forest = self._to_device(bds.forest_arrays())
-                        roots_dev = self._to_device(bds.roots_array())
+                        roots = bds.roots_array()
+                        roots_dev = self._to_device(roots)
                         stats.n_h2d += 1
+                        state = self._get_forget_matches()(state)
+                        mirror.forget()
                 kv = key
                 if e.temperature > 0:  # greedy verify never uses the key
                     key, kv = jax.random.split(key)
@@ -965,6 +1052,10 @@ class SpecEngine:
                             outs[r], K
                         )
                         mask = active.copy()
+                        mirror.advance(
+                            mirror.feeds(K, mask, roots, budgets_np),
+                            alive & mask, n_take,
+                        )
                         stats.n_rounds += 1
                         stats.n_fwd += 1
                         stats.n_toks_proposed += int((1 + n_prop[mask]).sum())
@@ -1154,14 +1245,17 @@ class SpecEngine:
         # mirrors above only drive budget solving and bookkeeping.
         state = None
         forest = forest_src = None
-        roots_dev = None
+        roots = roots_dev = None
         last_ver = -1
+        mirror = None
         if fused:
             state = self._to_device(make_state(
                 head, np.full((n_slots, bds.tail_len), -1, np.int32),
                 active, emitted, max_new_arr,
             ))
             stats.n_h2d += 5
+            mirror = _MatcherMirror(n_slots, bds.tail_len,
+                                    *self._matcher_counters)
 
         pending = None  # in-flight round (see dispatch/consume)
         finalize_q = collections.deque()  # finished; observation deferred
@@ -1398,6 +1492,7 @@ class SpecEngine:
                                 mn_pad, em_pad,
                             )
                         stats.n_h2d += 5
+                        mirror.admit(slots_pad[:kk])
                         roots_dirty = True
 
         def consume() -> None:
@@ -1414,7 +1509,7 @@ class SpecEngine:
             if pending is None:
                 return
             if pending[0] == "fused":
-                _, outs_dev, K, mask = pending
+                _, outs_dev, K, mask, fed = pending
                 pending = None
                 outs = np.asarray(outs_dev)  # dascheck: disable=DAS001 -- the fused round's one download
                 stats.n_d2h += 1
@@ -1423,6 +1518,7 @@ class SpecEngine:
                     outs[0], K
                 )
                 alive = alive & mask
+                mirror.advance(fed, alive, n_take)
             else:
                 _, res, block, budgets, mask = pending
                 pending = None
@@ -1605,6 +1701,7 @@ class SpecEngine:
                 pad[: len(evicted)] = evicted
                 state = self._get_evict_state()(state, pad)
                 stats.n_h2d += 1
+                mirror.forget(evicted)
 
         def precompute_budgets():
             """Round t+1 budgets from bounded-staleness emitted counts —
@@ -1656,8 +1753,10 @@ class SpecEngine:
             tree mutations (finalize observations) or slot turnover
             (admissions). Called from the overlap window so the repack
             and the roots upload hide behind the in-flight round; the
-            dispatch-side call is a startup/late-repack fallback."""
-            nonlocal forest, forest_src, roots_dev, last_ver, roots_dirty
+            dispatch-side call is a startup/late-repack fallback. The
+            rows' carried matchers reset behind the round in flight."""
+            nonlocal forest, forest_src, roots, roots_dev, last_ver, \
+                roots_dirty, state
             with tel_obs.span("history_sync") as sp_s:
                 bds.prewarm()
                 last_ver = bds.repack_version
@@ -1669,6 +1768,8 @@ class SpecEngine:
                     up = sum(a.nbytes for a in forest_src)
                 roots = bds.roots_array()
                 roots_dev = self._to_device(roots)
+                state = self._get_forget_matches()(state)
+                mirror.forget(in_flight=pending is not None)
                 if tel_obs.enabled:
                     self._mx["forest_upload"].inc(float(up + roots.nbytes))
                 stats.n_h2d += 1
@@ -1701,11 +1802,12 @@ class SpecEngine:
                     self.drafter.stats["batched_proposes"] += 1
                 stats.host_time_s += time.perf_counter() - t_h
                 stats.n_h2d += 1  # the (B,) budget vector
+                fed = mirror.feeds(K, active, roots, budgets)
                 cache, state, outs_dev, _ = self._get_fused(K, 1)(
                     self.params, forest, cache, state, roots_dev,
                     budgets.astype(np.int32), kv,
                 )
-                pending = ("fused", outs_dev, K, active.copy())
+                pending = ("fused", outs_dev, K, active.copy(), fed)
             else:
                 block = np.zeros((n_slots, K + 1), np.int32)
                 block[:, 0] = head

@@ -1,6 +1,7 @@
 from . import ops, ref
 from .ops import (
     ChunkedForest,
+    MatchRegs,
     PackedForest,
     pack_forest,
     pack_forest_chunked,
@@ -13,6 +14,7 @@ __all__ = [
     "ops",
     "ref",
     "ChunkedForest",
+    "MatchRegs",
     "PackedForest",
     "pack_forest",
     "pack_forest_chunked",

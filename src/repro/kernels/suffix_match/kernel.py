@@ -37,6 +37,17 @@ continuation. ``best_child`` is baked host-side at pack time from the
 epoch-decayed weights, so the device walk is pure pointer-chasing — no
 floats cross the host/device boundary.
 
+Matching statistics are a stream, so the feed can resume: given the
+registers (``MatchRegs``) a previous feed left against the same tree,
+feeding only the tail tokens appended since reaches the registers a
+full feed of the m-token tail reaches. The registers after a feed are
+the canonical locus of the longest suffix of the last m context tokens
+that occurs in the tree; a resumed feed keeps ``mlen <= m`` by taking
+one suffix-link hop before it feeds a token while ``mlen == m`` (a full
+feed from the root never meets that cap). The fused round
+(``core/fused_round.py``) carries the registers across rounds this way;
+every other caller starts at the root.
+
 Control-flow shape matters more than FLOPs here. Two deliberate choices
 keep the core fast both vmapped on CPU (the fallback in ``ref.py``) and
 as a per-row pallas program:
@@ -83,6 +94,7 @@ Invariants inherited from ``SuffixTree.pack()``:
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +109,18 @@ def _i32(x):
     return jnp.asarray(x, jnp.int32)
 
 
+class MatchRegs(NamedTuple):
+    """A matcher's registers: exactly at ``node`` (``child == -1``) or
+    ``epos`` tokens into the edge that leads to ``child``, with ``mlen``
+    context tokens matched. ``node == -1`` stands for the row's root
+    (a fresh matcher). Scalars per row, or ``(B,)`` arrays batched."""
+
+    node: jnp.ndarray
+    child: jnp.ndarray
+    epos: jnp.ndarray
+    mlen: jnp.ndarray
+
+
 def match_propose_row(
     e_node, e_tok, e_child,  # (E,) sorted (node, token) -> child edges
     sl, es, el, ft, bc,  # (N,) node table
@@ -104,15 +128,21 @@ def match_propose_row(
     tail,  # (m,) int32 context tail, -1 = padding/reset
     root,  # scalar int32 root node of this row's tree; < 0 = inactive
     budget,  # scalar int32 draft budget for this row
+    start=None,  # (MatchRegs, first tail index to feed); None = root, 0
     *,
     n_prop_max: int,
     min_match: int,
 ):
     """Scalar core shared by the pallas kernel and the jnp reference.
 
-    Returns (match_len, n_prop, props[(n_prop_max,)]) — bit-identical to
-    the host ``MatchState`` fed the same tail followed by
-    ``propose(budget, min_match)``.
+    Returns (match_len, n_prop, props[(n_prop_max,)], regs) — the first
+    three bit-identical to the host ``MatchState`` fed the same tail
+    followed by ``propose(budget, min_match)``; ``regs`` are the
+    ``MatchRegs`` the feed ended on. ``start`` resumes a feed: the
+    registers an earlier feed of this row's context left in this tree,
+    and the index of the first tail token it has not fed. Resumed from
+    the registers of the context before ``tail[first]``, the feed ends
+    on the registers a feed of the whole tail from the root ends on.
     """
     active = root >= 0
     root_s = jnp.maximum(_i32(root), 0)
@@ -179,6 +209,9 @@ def match_propose_row(
         s_child = jnp.where(full, _i32(-1), new_child)
         s_epos = jnp.where(full, _i32(0), new_epos)
         dead = mlen == 0
+        # at the cap the match first drops its oldest token (one hop),
+        # so it never spans more than the last m context tokens
+        step_ok = step_ok & (mlen < m)
         hop = ~is_reset & ~step_ok & ~dead
         shift = (on_edge & (node == root_s)).astype(jnp.int32)
         feed_node = jnp.where(is_reset, root_s, jnp.where(step_ok, s_node, node))
@@ -207,11 +240,18 @@ def match_propose_row(
         )
 
     z = _i32(0)
-    i0 = jnp.where(active, 0, m).astype(jnp.int32)  # inactive rows skip
+    if start is None:
+        regs0, first = MatchRegs(root_s, _i32(-1), z, z), z
+    else:
+        (node0, child0, epos0, mlen0), first = start
+        regs0 = MatchRegs(jnp.where(node0 < 0, root_s, _i32(node0)),
+                          _i32(child0), _i32(epos0), _i32(mlen0))
+    i0 = jnp.where(active, first, m).astype(jnp.int32)  # inactive rows skip
     _, node, child, epos, mlen, _, _, _, _ = jax.lax.while_loop(
         fcond, fbody,
-        (i0, root_s, _i32(-1), z, z, _i32(_FEED), root_s, z, z),
+        (i0, *regs0, _i32(_FEED), root_s, z, z),
     )
+    regs = MatchRegs(node, child, epos, mlen)
 
     # ---- greedy continuation walk with shorter-suffix fallback -------
     # Same flat shape: walk micro-steps emit tokens; an empty walk hops
@@ -301,7 +341,7 @@ def match_propose_row(
     match_len = jnp.where(active, mlen, 0).astype(jnp.int32)
     n_prop = jnp.where(active, n_prop, 0).astype(jnp.int32)
     props = jnp.where(active, props, -1).astype(jnp.int32)
-    return match_len, n_prop, props
+    return match_len, n_prop, props, regs
 
 
 def _suffix_match_kernel(
@@ -318,7 +358,7 @@ def _suffix_match_kernel(
     n_prop_max: int,
     min_match: int,
 ):
-    match_len, n_prop, props = match_propose_row(
+    match_len, n_prop, props, _ = match_propose_row(
         en_ref[...], et_ref[...], ec_ref[...],
         sl_ref[...], es_ref[...], el_ref[...], ft_ref[...], bc_ref[...],
         corpus_ref[...],

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .kernel import (
+    MatchRegs,
     match_propose_row,
     suffix_match_propose_kernel,
     suffix_match_propose_kernel_chunked,
@@ -217,39 +218,47 @@ def pack_forest_chunked(
     return forest, np.arange(len(packs), dtype=np.int32)
 
 
-def _propose_chunked_ref(forest, tails, roots, budgets, *, n_prop_max,
-                         min_match):
+def _propose_chunked_ref(forest, tails, roots, budgets, start=None, *,
+                         n_prop_max, min_match):
     """Chunked-layout jnp fallback: vmap the scalar core over rows,
     gathering each row's tree chunk (the CPU/oracle twin of the
-    scalar-prefetch streamed pallas variant)."""
+    scalar-prefetch streamed pallas variant). Registers are tree-local."""
     T = forest.edge_node.shape[0]
     tidx = jnp.clip(roots, 0, T - 1).astype(jnp.int32)
     root_local = jnp.where(roots >= 0, 0, -1).astype(jnp.int32)
 
-    def one(t, tail, root, budget):
+    def one(t, tail, root, budget, st):
         return match_propose_row(
             forest.edge_node[t], forest.edge_tok[t], forest.edge_child[t],
             forest.suffix_link[t], forest.edge_start[t], forest.edge_len[t],
             forest.first_tok[t], forest.best_child[t], forest.corpus[t],
-            tail, root, budget,
+            tail, root, budget, st,
             n_prop_max=n_prop_max, min_match=min_match,
         )
 
-    return jax.vmap(one)(tidx, tails, root_local, budgets)
+    out = jax.vmap(one)(tidx, tails, root_local, budgets, start)
+    return out if start is not None else out[:3]
 
 
 # das: hot-path — trace-time dispatch, composed inside the fused round
 def propose_device(forest, tails, roots, budgets, *, n_prop_max,
-                   min_match, impl="ref", interpret=False):
+                   min_match, impl="ref", interpret=False, start=None):
     """Trace-time propose dispatch — usable standalone *or inside a
     larger jitted program* (the fused verify round composes it with the
     model forward). Routes on forest layout: flat forests use the
     shared-block kernel / vmapped reference, chunked forests the
-    scalar-prefetch streamed kernel / per-row gather reference."""
+    scalar-prefetch streamed kernel / per-row gather reference.
+
+    Returns ``(match_len, n_prop, props)``. ``start`` — ``((B,)
+    MatchRegs, (B,) first tail index)`` — resumes each row's feed from
+    carried registers (XLA core only) and appends the rows' final
+    ``MatchRegs`` to the result."""
+    if start is not None and impl != "ref":
+        raise ValueError("a resumed feed runs on the XLA core (impl='ref')")
     if isinstance(forest, ChunkedForest):
         if impl == "ref":
             return _propose_chunked_ref(
-                forest, tails, roots, budgets,
+                forest, tails, roots, budgets, start,
                 n_prop_max=n_prop_max, min_match=min_match,
             )
         return suffix_match_propose_kernel_chunked(
@@ -258,7 +267,7 @@ def propose_device(forest, tails, roots, budgets, *, n_prop_max,
         )
     if impl == "ref":
         return suffix_match_propose_ref(
-            tails, roots, budgets, *forest,
+            tails, roots, budgets, *forest, start,
             n_prop_max=n_prop_max, min_match=min_match,
         )
     return suffix_match_propose_kernel(

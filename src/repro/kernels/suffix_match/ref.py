@@ -33,15 +33,19 @@ def suffix_match_propose_ref(
     first_tok: jnp.ndarray,
     best_child: jnp.ndarray,
     corpus: jnp.ndarray,
+    start=None,  # ((B,) MatchRegs, (B,) first tail index) to resume from
     *,
     n_prop_max: int,
     min_match: int,
 ):
-    def one(tail, root, budget):
+    """(match_len, n_prop, props); with ``start``, also the rows' final
+    ``MatchRegs`` (see ``kernel.match_propose_row``)."""
+    def one(tail, root, budget, st):
         return match_propose_row(
             edge_node, edge_tok, edge_child, suffix_link, edge_start,
             edge_len, first_tok, best_child, corpus, tail, root, budget,
-            n_prop_max=n_prop_max, min_match=min_match,
+            st, n_prop_max=n_prop_max, min_match=min_match,
         )
 
-    return jax.vmap(one)(tails, roots, budgets)
+    out = jax.vmap(one)(tails, roots, budgets, start)
+    return out if start is not None else out[:3]

@@ -130,6 +130,137 @@ def test_fused_ssm_family_runs_and_matches():
     assert runs["on"][1] == runs["off"][1]
 
 
+def _matcher_values(tel):
+    reg = tel.registry
+    return (
+        reg.value("das_matcher_rows_total", (("feed", "carried"),)),
+        reg.value("das_matcher_rows_total", (("feed", "full"),)),
+        reg.value("das_matcher_full_rounds_total"),
+    )
+
+
+def _serve_with_cancel(eng, stats):
+    """Serve 24 requests on the epoch-0 problems, their prompts extended
+    so rollouts stray from the history, into 4 slots (admissions
+    mid-stream; finished rollouts publish history, so the forest repacks
+    between rounds); when the first request finishes, cancel the
+    lowest-rid resident one (an eviction). Returns outputs by rid."""
+    from repro.core.scheduler import Request
+
+    reqs = [Request(rid=i, problem_id=PIDS[i % 8],
+                    prompt=PROMPTS[i % 8] + [2 + i // 8],
+                    max_new_tokens=24 - i % 5) for i in range(24)]
+    cancelled = False
+    for done in eng.serve(reqs, slots=4, key=jax.random.key(7),
+                          stats=stats):
+        if not cancelled:
+            resident = [r for r in reqs if r.slot >= 0 and r is not done
+                        and r.finish_round < 0]
+            if resident:
+                resident[0].cancel_requested = cancelled = True
+    return {r.rid: list(r.output) for r in reqs}
+
+
+@pytest.mark.parametrize("tail", [8, 64])
+def test_fused_carried_matcher_serve_parity_and_feed_counts(tail):
+    """Serving with admissions mid-stream, forest repacks between rounds
+    and an eviction: the fused round, whose matcher carries its
+    registers across rounds, stays round-for-round identical to the
+    unfused round (a tail of 8 makes copied text outgrow it). The
+    host's ``das_matcher_*`` counters equal what the device state each
+    round starts from says, and a round feeds rows in full exactly when
+    a new forest or new roots were uploaded since the last round that
+    fed: admitted rows wait for that sync unfed, evicted rows leave."""
+    from repro import obs
+    from repro.core.fused_round import matcher_feeds
+    from repro.core.spec_engine import RolloutStats
+
+    params = make_params(DENSE)
+    runs = {}
+    for fuse in ("on", "off"):
+        tel = obs.Telemetry()
+        eng = SpecEngine(
+            params, DENSE,
+            EngineConfig(max_new_tokens=24, max_draft=4,
+                         block_buckets=(0, 2, 4), eos_token=1,
+                         device_draft="on", fuse_rounds=fuse),
+            drafter=SuffixDrafter(DrafterConfig(
+                scope="problem", min_match=1, window_size=16,
+                device_tail=tail)),
+            telemetry=tel,
+        )
+        eng.begin_iteration(0)
+        eng.generate(PROMPTS, PIDS, max_new_tokens=LIMITS,
+                     key=jax.random.key(5))
+        eng.begin_iteration(1)
+        rounds, events = [], []
+        if fuse == "on":
+            get_fused, get_forget = eng._get_fused, eng._get_forget_matches
+
+            def fused(K, R, get_fused=get_fused, tel=tel, events=events):
+                fn = get_fused(K, R)
+
+                def call(params, forest, cache, state, roots, budgets, key):
+                    node = np.asarray(state.match.node)
+                    fed = np.zeros_like(node, bool)
+                    if K > 0:
+                        fed = matcher_feeds(
+                            np.asarray(state.active), np.asarray(roots) >= 0,
+                            np.asarray(budgets), node >= 0,
+                            np.asarray(state.feed_from), tail)
+                    rounds.append((K, int((fed & (node >= 0)).sum()),
+                                   int((fed & (node < 0)).sum()),
+                                   _matcher_values(tel)))
+                    events.append("round")
+                    return fn(params, forest, cache, state, roots, budgets,
+                              key)
+
+                return call
+
+            def forget(get_forget=get_forget, events=events):
+                events.append("sync")
+                return get_forget()
+
+            eng._get_fused, eng._get_forget_matches = fused, forget
+        stats = RolloutStats()
+        runs[fuse] = (_serve_with_cancel(eng, stats), stats, rounds, events,
+                      eng)
+    (o_on, st_on, rounds, events, eng), (o_off, st_off, *_) = (
+        runs["on"], runs["off"])
+    assert o_on == o_off
+    assert st_on.n_drafted == st_off.n_drafted > 0
+    assert st_on.n_accepted == st_off.n_accepted
+    assert st_on.round_accepts == st_off.round_accepts
+    assert eng._evict_state_fn is not None  # the cancel evicted a row
+    # host counters, round by round, equal the device state's truth
+    prev = (0.0, 0.0, 0.0)
+    for K, n_carried, n_full, vals in rounds:
+        assert vals[0] - prev[0] == n_carried
+        assert vals[1] - prev[1] == n_full
+        assert vals[2] - prev[2] == (n_full > 0)
+        prev = vals
+    assert prev[0] > 0 and prev[1] > 0
+    # full rounds: exactly the rounds that feed after a sync
+    full, synced, after_sync = [], False, []
+    it = iter(rounds)
+    for ev in events:
+        if ev == "sync":
+            synced = True
+            continue
+        K, _, n_full, _ = next(it)
+        full.append(n_full > 0)
+        if K > 0:
+            after_sync.append(synced)
+            synced = False
+        else:
+            after_sync.append(False)
+    if tail >= max(LIMITS):  # a carried row can never drop off its tail
+        assert full == after_sync
+    else:
+        assert all(f for f, a in zip(full, after_sync) if a)
+    assert 0 < sum(full) <= events.count("sync")
+
+
 def test_fused_respects_exact_limits_and_head_only_rows():
     """Per-row max_new_tokens stays a hard cap through the fused emit
     scan, including limit=1 (head token fills it, no round)."""

@@ -385,6 +385,43 @@ def test_forest_upload_bytes_and_queue_wait(monkeypatch):
     assert wait.sum == sum(r.admit_round for r in reqs) > 0
 
 
+def _matcher_counts(tel):
+    reg = tel.registry
+    return (reg.value("das_matcher_rows_total", (("feed", "carried"),)),
+            reg.value("das_matcher_rows_total", (("feed", "full"),)),
+            reg.value("das_matcher_full_rounds_total"))
+
+
+@pytest.mark.parametrize("fuse", ["on", "off"])
+def test_matcher_feed_counters(fuse):
+    """``das_matcher_rows_total{feed}`` counts the rows each fused round's
+    matcher fed, from carried registers or in full, and
+    ``das_matcher_full_rounds_total`` the rounds with any full feed. A
+    warm lock-step epoch packs its forest before round one and uploads
+    no other, so its first drafting round feeds every row in full and
+    every later one feeds from carried registers. Unfused rounds carry
+    nothing and count nothing."""
+    tel = obs.Telemetry()
+    eng = _engine(make_params(DENSE), fuse=fuse, telemetry=tel)
+    eng.begin_iteration(0)
+    eng.generate(PROMPTS, PIDS, key=jax.random.key(5))
+    eng.begin_iteration(1)
+    before = _matcher_counts(tel)
+    _, stats = eng.generate(PROMPTS, PIDS, key=jax.random.key(7),
+                            collect_effective_batch=True)
+    carried, full, full_rounds = (
+        b - a for a, b in zip(before, _matcher_counts(tel)))
+    if fuse == "off":
+        assert (carried, full, full_rounds) == (0, 0, 0)
+        return
+    assert full_rounds == 1 and full == len(PROMPTS)
+    assert 0 < carried <= sum(stats.effective_batch) - full
+    text = tel.prometheus()
+    assert 'das_matcher_rows_total{feed="carried"}' in text
+    assert 'das_matcher_rows_total{feed="full"}' in text
+    assert "das_matcher_full_rounds_total" in text
+
+
 def test_metrics_server_live_serve():
     params = make_params(DENSE)
     tel = obs.Telemetry()

@@ -7,6 +7,9 @@ to the host ``MatchState`` fed that tail followed by
 document removal, and interleaved extend/evict via the drafter window.
 """
 
+import functools
+
+import jax
 import numpy as np
 import pytest
 from conftest import hypothesis_or_stub
@@ -386,6 +389,154 @@ def test_kernel_parity_property(docs, ctxs, window, decay, budgets,
         bds.open(b, "p", ctx)
         host.append(d.new_session("p", list(ctx[-TAIL:])).propose(budgets[b]))
     assert bds.propose_batch(budgets) == host
+
+
+# ---------------------------------------------------------------------------
+# carried registers: a feed resumed from the registers the previous feed
+# left ≡ a feed of the whole tail from the root
+# ---------------------------------------------------------------------------
+CARRY_TAIL = 8  # small tail: copied text outgrows it, so the cap binds
+
+
+@functools.lru_cache(maxsize=None)
+def _propose_fn(resumed: bool):
+    from repro.kernels.suffix_match import propose_device
+
+    def fn(forest, tails, roots, budgets, regs, first):
+        return propose_device(
+            forest, tails, roots, budgets, n_prop_max=KMAX, min_match=1,
+            start=(regs, first) if resumed else None,
+        )
+
+    return jax.jit(fn)
+
+
+def _root_regs(n):
+    from repro.kernels.suffix_match import MatchRegs
+
+    return MatchRegs(*(np.full(n, v, np.int32) for v in (-1, -1, 0, 0)))
+
+
+def _stream(plan, docs):
+    """Tokens from ``plan`` segments: copies of corpus text (the match
+    grows past the tail), tokens outside the corpus alphabet (the match
+    breaks), resets (-1)."""
+    out = []
+    for seg in plan:
+        if seg[0] == "copy":
+            d = docs[seg[1] % len(docs)]
+            i = seg[2] % len(d)
+            out += [int(t) for t in d[i:i + seg[3]]]
+        elif seg[0] == "tok":
+            out += [int(t) for t in seg[1]]
+        else:
+            out.append(-1)
+    return out
+
+
+def _check_carried_feed(docs, plans, chunks, budgets, layout):
+    """Feed every row's stream in chunks from carried registers and, after
+    each chunk, compare with a feed of the last ``CARRY_TAIL`` tokens from
+    the root: registers, match length and proposals identical. A chunk
+    longer than the tail restarts the row at the root, as the fused round
+    does. Returns how often the match spanned the whole tail."""
+    m = CARRY_TAIL
+    trees = [_mk_tree(docs[: len(docs) // 2 + 1]), _mk_tree(docs)]
+    packs = [t.pack() for t in trees]
+    if layout == "flat":
+        forest, troots = pack_forest(packs)
+    else:
+        forest, troots = pack_forest_chunked(
+            packs, min_stride_nodes=64, min_stride_edges=64,
+            min_stride_corpus=64,
+        )
+    n = len(plans)
+    streams = [_stream(p, docs) for p in plans]
+    roots = np.array([troots[b % 2] for b in range(n)], np.int32)
+    budgets = np.asarray(budgets, np.int32)
+    steps = max(len(c) for c in chunks)
+    sizes = [[c[i % len(c)] for i in range(steps)] for c in chunks]
+    # each row's stream, repeated to cover its chunks
+    streams = [s * (sum(k) // len(s) + 1) for s, k in zip(streams, sizes)]
+    regs, pos, n_cap = _root_regs(n), [0] * n, 0
+    for step in range(steps):
+        tails = np.full((n, m), -1, np.int32)
+        first = np.zeros(n, np.int32)
+        for b in range(n):
+            k = sizes[b][step]
+            pos[b] += k
+            tail = streams[b][max(pos[b] - m, 0):pos[b]]
+            tails[b, m - len(tail):] = tail
+            first[b] = m - k if k <= m else 0
+        restart = first == 0
+        regs = type(regs)(*(np.where(restart, z, r)
+                            for r, z in zip(regs, _root_regs(n))))
+        got = _propose_fn(True)(forest, tails, roots, budgets, regs, first)
+        want = _propose_fn(True)(forest, tails, roots, budgets,
+                                 _root_regs(n), np.zeros(n, np.int32))
+        plain = _propose_fn(False)(forest, tails, roots, budgets,
+                                   regs, first)
+        got, want = jax.tree.map(np.asarray, (got, want))
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert np.array_equal(g, w), (step, tails, g, w)
+        for g, p in zip(got[:3], plain):
+            assert np.array_equal(g, np.asarray(p))
+        n_cap += int((got[0] == m).sum())
+        regs = got[3]
+    return n_cap
+
+
+segment = st.one_of(
+    st.tuples(st.just("copy"), st.integers(0, 9), st.integers(0, 23),
+              st.integers(1, 24)),
+    st.tuples(st.just("tok"), st.lists(st.integers(0, 9), min_size=1,
+                                       max_size=4)),
+    st.tuples(st.just("reset")),
+)
+
+
+@settings(max_examples=25, deadline=None)
+@given(
+    docs=st.lists(doc, min_size=2, max_size=6),
+    plans=st.lists(st.lists(segment, min_size=1, max_size=6),
+                   min_size=B, max_size=B),
+    chunks=st.lists(st.lists(st.integers(1, KMAX + 1), min_size=1,
+                             max_size=12), min_size=B, max_size=B),
+    budgets=st.lists(st.integers(0, KMAX), min_size=B, max_size=B),
+    layout=st.sampled_from(["flat", "chunked"]),
+)
+def test_carried_feed_matches_full_feed_property(docs, plans, chunks,
+                                                 budgets, layout):
+    _check_carried_feed(docs, plans, chunks, budgets, layout)
+
+
+@pytest.mark.parametrize("layout", ["flat", "chunked"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_carried_feed_matches_full_feed(layout, seed):
+    """Seeded twin of the property test: long copies of corpus text so
+    the tail cap binds, tokens that leave the tree, resets."""
+    rng = np.random.default_rng(seed)
+    docs = [list(rng.integers(0, 4, size=int(rng.integers(12, 24))))
+            for _ in range(4)]
+    plans = []
+    for _ in range(B):
+        plan = []
+        for _ in range(6):
+            r = rng.random()
+            if r < 0.6:
+                plan.append(("copy", int(rng.integers(0, 4)),
+                             int(rng.integers(0, 4)),
+                             int(rng.integers(CARRY_TAIL + 2, 24))))
+            elif r < 0.9:
+                plan.append(("tok", [int(t) for t in
+                                     rng.integers(4, 10, size=2)]))
+            else:
+                plan.append(("reset",))
+        plans.append(plan)
+    chunks = [[int(k) for k in rng.integers(1, KMAX + 2, size=12)]
+              for _ in range(B)]
+    budgets = [int(b) for b in rng.integers(0, KMAX + 1, size=B)]
+    assert _check_carried_feed(docs, plans, chunks, budgets, layout) > 0
 
 
 # ---------------------------------------------------------------------------
