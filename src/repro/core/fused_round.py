@@ -14,7 +14,8 @@ per (K-bucket, forest geometry):
     propose (XLA suffix-match core over the packed forest)
       → build the (B, K+1) verify block on device
       → model forward + ``verify_block`` acceptance
-      → cache commit (ring-slot overwrite / staged recurrent gather)
+      → cache commit (the block's ring slots, written in place inside the
+        layer scan / staged recurrent gather)
       → EOS/limit emit scan
       → next-round session state (head, context tails, emitted, active)
 

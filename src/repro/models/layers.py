@@ -414,6 +414,7 @@ def attention_forward(
     mrope_positions=None,
     cross_kv: Optional[Tuple] = None,  # (k, v, valid_mask) for cross-attn
     attn_impl: str = "xla",  # xla | pallas (cached path only)
+    layer=None,  # index into a stacked kv_cache's leading layer axis
 ):
     """Returns (y, new_kv_cache).
 
@@ -425,6 +426,13 @@ def attention_forward(
     speculative rollback is free for attention layers. For windowed
     caches S = window + headroom (headroom >= max draft block) so a
     multi-token block never clobbers in-window entries.
+
+    With ``layer`` given, kv_cache is the whole stacked cache of a
+    scanned stage, (R, B, S+1, ...), carried through the layer scan: the
+    block's K/V and positions are scattered at ``[layer, row, slot]``,
+    the attention reads that layer, and the stacked triple comes back.
+    Only the block's slots are written, so the update happens in place
+    in the (donated) pool instead of rewriting a whole layer per step.
     """
     B, T, _ = x.shape
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"])
@@ -468,32 +476,37 @@ def attention_forward(
         return y, (k, v, positions)
 
     ck, cv, cpos = kv_cache
-    S = ck.shape[1] - 1  # last slot is the trash slot
+    S = ck.shape[-3] - 1  # last slot is the trash slot
     if valid is None:
         slots = positions % S
         pos_write = positions
     else:
         slots = jnp.where(valid, positions % S, S)
         pos_write = jnp.where(valid, positions, -1)
-    bidx = jnp.arange(B)[:, None]
-    ck = ck.at[bidx, slots].set(k.astype(ck.dtype))
-    cv = cv.at[bidx, slots].set(v.astype(cv.dtype))
-    cpos = cpos.at[bidx, slots].set(pos_write)
+    at = (jnp.arange(B)[:, None], slots)
+    if layer is not None:
+        at = (layer,) + at
+    ck = ck.at[at].set(k.astype(ck.dtype))
+    cv = cv.at[at].set(v.astype(cv.dtype))
+    cpos = cpos.at[at].set(pos_write)
+    lk, lv, lpos = (ck, cv, cpos) if layer is None else (
+        ck[layer], cv[layer], cpos[layer]
+    )
     if attn_impl == "pallas":
         from repro.kernels.spec_verify import ops as sv_ops  # lazy
 
         out = sv_ops.spec_verify_attention(
-            q, ck, cv, cpos, positions, window=window,
+            q, lk, lv, lpos, positions, window=window,
             softcap=cfg.logit_softcap,
         )
     else:
         qpos = positions[:, :, None]  # (B,T,1)
-        kpos = cpos[:, None, :]  # (B,1,S+1)
+        kpos = lpos[:, None, :]  # (B,1,S+1)
         mask = (kpos >= 0) & (kpos <= qpos)
         if window > 0:
             mask &= kpos > qpos - window
         out = _attn_core(
-            q, ck.astype(q.dtype), cv.astype(q.dtype), mask[:, None], cfg
+            q, lk.astype(q.dtype), lv.astype(q.dtype), mask[:, None], cfg
         )
     y = jnp.einsum("bthk,hkd->btd", out, p["wo"])
     return y, (ck, cv, cpos)

@@ -188,9 +188,10 @@ def init_cache(
 def _run_block(
     p, kind, x, cfg: ModelConfig, *, positions, cache, valid, commit_upto,
     mrope_positions=None, enc_out=None, enc_mask=None, attn_impl="xla",
-    cross_kv=None, collect_states=False,
+    cross_kv=None, collect_states=False, layer=None,
 ):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux). ``layer`` indexes a stacked attention
+    cache (see ``L.attention_forward``)."""
     aux = jnp.zeros((), jnp.float32)
     h = L.apply_norm(p["norm"], x, cfg)
     new_cache = cache
@@ -200,8 +201,9 @@ def _run_block(
             p["attn"], h, cfg, positions=positions, window=window,
             kv_cache=cache, valid=valid, mrope_positions=mrope_positions,
             attn_impl=attn_impl if cache is not None else "xla",
+            layer=layer,
         )
-        new_cache = kv_out if cache is not None else kv_out
+        new_cache = kv_out
         x = x + y
         if cfg.is_encoder_decoder and (enc_out is not None or cross_kv is not None):
             hc = L.apply_norm(p["cross_norm"], x, cfg)
@@ -343,7 +345,17 @@ def forward(
     With return_hidden=True, returns the final-norm hidden states
     (B,T,D) instead of logits — callers then use a *chunked* logprob
     computation (rl.grpo.chunked_token_logprobs) so the (B,S,V) fp32
-    logits tensor is never materialized (large-vocab training)."""
+    logits tensor is never materialized (large-vocab training).
+
+    A scanned stage with a cache carries its stacked *attention* caches
+    in the scan's carry, and each layer updates them in place at its
+    index (``jnp.arange(R)`` comes in as ``xs``): only the block's slots
+    are written, and the result aliases the donated pool. Passed as
+    ``xs``/``ys`` instead, XLA gives ``ys`` a fresh pool-sized buffer,
+    writes every layer's whole slice into it, and copies it into the
+    donated pool afterwards. Recurrent caches stay in ``xs``/``ys``:
+    they are small, and with ``collect_states`` their ``ys`` is a staged
+    (B, T+1, ...) shape that ``commit_staged_cache`` gathers from."""
     if embeds is None:
         emb = params["embed"]
         x = emb[tokens].astype(jnp.dtype(cfg.dtype))
@@ -362,31 +374,49 @@ def forward(
         stage_c = cache.stages[si] if cache is not None else None
         stage_x = cross_cache[si] if cross_cache is not None else None
         if repeats > 1:
-            def scan_body(carry, xs, unit=unit):
-                x, aux = carry
-                p_slice, c_slice, x_slice = xs
-                c_new_unit = []
+            held = tuple(
+                stage_c is not None and kind in ("attn", "local_attn")
+                for kind in unit
+            )
+            c_carry = tuple(
+                stage_c[ui] if h else None for ui, h in enumerate(held)
+            )
+            c_xs = tuple(
+                None if h or stage_c is None else stage_c[ui]
+                for ui, h in enumerate(held)
+            )
+
+            def scan_body(carry, xs, *, unit=unit, held=held):
+                x, aux, c_carry = carry
+                p_slice, layer, c_slice, x_slice = xs
+                c_carry, c_ys = list(c_carry), []
                 for ui, kind in enumerate(unit):
-                    cu = c_slice[ui] if c_slice is not None else None
                     xc = x_slice[ui] if x_slice is not None else None
                     x, cu_new, a = _run_block(
                         p_slice[ui], kind, x, cfg, positions=positions,
-                        cache=cu, valid=valid, commit_upto=commit_upto,
+                        cache=c_carry[ui] if held[ui] else c_slice[ui],
+                        valid=valid, commit_upto=commit_upto,
                         mrope_positions=mrope_positions, enc_out=enc_out,
                         enc_mask=enc_mask, attn_impl=attn_impl,
                         cross_kv=xc, collect_states=collect_states,
+                        layer=layer if held[ui] else None,
                     )
-                    c_new_unit.append(cu_new)
+                    if held[ui]:
+                        c_carry[ui] = cu_new
+                    c_ys.append(None if held[ui] else cu_new)
                     aux = aux + a
                 x = constrain(x)  # sequence-parallel residual (training)
-                return (x, aux), tuple(c_new_unit)
+                return (x, aux, tuple(c_carry)), tuple(c_ys)
 
             if remat:
                 scan_body = jax.checkpoint(scan_body, prevent_cse=False)
-            (x, aux_total), stage_c_new = jax.lax.scan(
-                scan_body, (x, aux_total), (stage_p, stage_c, stage_x)
+            (x, aux_total, c_carry), c_ys = jax.lax.scan(
+                scan_body, (x, aux_total, c_carry),
+                (stage_p, jnp.arange(repeats), c_xs, stage_x),
             )
-            new_stages.append(stage_c_new)
+            new_stages.append(tuple(
+                c_carry[ui] if h else c_ys[ui] for ui, h in enumerate(held)
+            ))
         else:
             c_new_unit = []
             for ui, kind in enumerate(unit):

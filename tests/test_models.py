@@ -1,6 +1,8 @@
 """Cached decode == full forward, for every block family (the invariant
 that makes speculative verification exact)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ import pytest
 
 from conftest import make_params
 from repro.configs.base import ModelConfig
+from repro.models import layers as L
 from repro.models import model as M
 
 BASE = dict(
@@ -262,3 +265,103 @@ def test_flash_attention_matches_dense():
         np.testing.assert_allclose(
             np.asarray(flash), np.asarray(ref), atol=3e-5, rtol=1e-4
         )
+
+
+def _layer_loop_forward(params, cfg, block, cache, valid, collect):
+    """The cached forward as a Python loop over unstacked layers."""
+    B, T = block.shape
+    x = params["embed"][block].astype(jnp.dtype(cfg.dtype))
+    positions = cache.lengths[:, None] + jnp.arange(T)[None]
+    commit_upto = None if collect else jnp.zeros((B,), jnp.int32)
+    stages = []
+    for si, (unit, repeats) in enumerate(cfg.scan_stages):
+        sp, sc = params["stages"][si], cache.stages[si]
+        per_layer = []
+        for r in range(repeats):
+            def take(tree, r=r, stacked=repeats > 1):
+                return jax.tree.map(lambda a: a[r], tree) if stacked else tree
+
+            outs = []
+            for ui, kind in enumerate(unit):
+                x, c, _ = M._run_block(
+                    take(sp[ui]), kind, x, cfg, positions=positions,
+                    cache=take(sc[ui]), valid=valid, commit_upto=commit_upto,
+                    collect_states=collect,
+                )
+                outs.append(c)
+            per_layer.append(tuple(outs))
+        stages.append(
+            jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+            if repeats > 1 else per_layer[0]
+        )
+    x = L.apply_norm(params["final_norm"], x, cfg)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("btd,dv->btv", x, head).astype(jnp.float32)
+    return logits, M.Cache(tuple(stages), cache.lengths)
+
+
+IN_PLACE_UNITS = {
+    "attn": dict(family="dense", num_layers=3),
+    "local_attn+attn": dict(
+        family="dense", block_pattern=("local_attn", "attn"), num_layers=4,
+        local_window=6,
+    ),
+    "rglru+local_attn": dict(
+        family="hybrid", block_pattern=("rglru", "rglru", "local_attn"),
+        num_layers=6, local_window=6, rnn_width=64,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(IN_PLACE_UNITS))
+def test_scanned_cache_update_equals_layer_loop(name):
+    """The layer scan's in-place update of the stacked attention cache
+    gives exactly what a loop over the unstacked layers gives — logits
+    and every cache leaf, recurrent staged states included — and leaves
+    every slot outside the verify block as it was."""
+    cfg = ModelConfig(name=name, **{**BASE, **IN_PLACE_UNITS[name]})
+    collect = name.startswith("rglru")
+    params = make_params(cfg)
+    B, Tp, K1 = 3, 8, 5
+    toks = jax.random.randint(jax.random.key(1), (B, Tp), 0, cfg.vocab_size)
+    mask = jnp.arange(Tp)[None] >= jnp.asarray([[3], [0], [5]])
+    _, cache = M.prefill(params, cfg, toks, mask, max_len=32, headroom=8)
+    block = jax.random.randint(jax.random.key(2), (B, K1), 0, cfg.vocab_size)
+    valid = jnp.broadcast_to(jnp.asarray([True, False, True])[:, None],
+                             (B, K1))
+
+    @jax.jit
+    def scanned(params, block, cache):
+        logits, new, _ = M.forward(
+            params, cfg, block, cache=cache, valid=valid,
+            commit_upto=None if collect else jnp.zeros((B,), jnp.int32),
+            collect_states=collect,
+        )
+        return logits, new
+
+    looped = jax.jit(functools.partial(
+        _layer_loop_forward, cfg=cfg, valid=valid, collect=collect
+    ))
+    logits, new = scanned(params, block, cache)
+    logits_ref, new_ref = looped(params, block=block, cache=cache)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(logits_ref))
+    leaves, ref_leaves = jax.tree.leaves(new), jax.tree.leaves(new_ref)
+    assert jax.tree.structure(new) == jax.tree.structure(new_ref)
+    for a, b in zip(leaves, ref_leaves):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # attention leaves: only the block's slots (valid rows) or the trash
+    # slot (invalid rows) may differ from the cache before the forward
+    pos = np.asarray(cache.lengths)[:, None] + np.arange(K1)[None]
+    for si, (unit, repeats) in enumerate(cfg.scan_stages):
+        for ui, kind in enumerate(unit):
+            if kind not in ("attn", "local_attn"):
+                continue
+            for old, upd in zip(cache.stages[si][ui], new.stages[si][ui]):
+                old, upd = np.asarray(old), np.asarray(upd)
+                if repeats == 1:
+                    old, upd = old[None], upd[None]
+                S = old.shape[2] - 1
+                kept = np.ones(old.shape[1:3], bool)  # (B, S+1)
+                for b in range(B):
+                    kept[b, pos[b] % S if bool(valid[b, 0]) else S] = False
+                np.testing.assert_array_equal(upd[:, kept], old[:, kept])

@@ -10,6 +10,7 @@ traffic, K buckets 0/4/8.
 """
 
 import os
+import re
 
 import jax
 import numpy as np
@@ -101,18 +102,65 @@ def _fits(compiled) -> None:
     )
 
 
+@pytest.fixture(scope="module")
+def fused(qwen):
+    """The fused round compiled for the chip, once per K bucket."""
+    done = {}
+
+    def get(K):
+        if K not in done:
+            q = qwen
+            done[K] = q["eng"]._get_fused(K, 1).lower(
+                q["params"], q["forest"], q["cache"], q["state"], q["i32"],
+                q["i32"], q["key"],
+            ).compile()
+        return done[K]
+
+    return get
+
+
 @pytest.mark.parametrize("K", (0, 4, 8))
-def test_fused_round_compiles_for_v5e(qwen, K):
+def test_fused_round_compiles_for_v5e(qwen, fused, K):
     """One fused round per K bucket: XLA suffix-match core (no Pallas
     custom call) + full-width verify forward + commit."""
     assert K in qwen["eng"].engine.block_buckets
-    q = qwen
-    compiled = q["eng"]._get_fused(K, 1).lower(
-        q["params"], q["forest"], q["cache"], q["state"], q["i32"],
-        q["i32"], q["key"],
-    ).compile()
+    compiled = fused(K)
     _fits(compiled)
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def _hlo_shape(shape, dtype) -> str:
+    """An array type as the HLO text spells it, e.g. ``bf16[2,3]``."""
+    name = {"bfloat16": "bf16", "float32": "f32"}[np.dtype(dtype).name]
+    return f"{name}[{','.join(map(str, shape))}]"
+
+
+def test_fused_round_updates_pool_in_place_on_v5e(qwen, fused):
+    """The layer scan writes only the verify block's slots into the
+    donated K/V pool: no copy of a whole pool, no dynamic-update-slice
+    of one (a layer written back into a fresh scan output), no whole
+    layer slice computed, and temporaries far below one pool."""
+    compiled = fused(8)
+    k_pool = qwen["cache"].stages[0][0][0]  # (layers, slots, S+1, Hkv, hd)
+    pool = re.escape(_hlo_shape(k_pool.shape, k_pool.dtype))
+    layer = re.escape(_hlo_shape(k_pool.shape[1:], k_pool.dtype))
+    op = r"%\S+ = {}\{{[^}}]*\}} ([\w-]+)\("
+    pool_ops, layer_ops = [], []
+    for line in compiled.as_text().splitlines():
+        m = re.search(op.format(pool), line)
+        if m and (m.group(1) == "copy" or "dynamic-update-slice" in line):
+            pool_ops.append(line.strip()[:160])
+        m = re.search(op.format(layer), line)
+        if m and m.group(1) != "parameter":
+            layer_ops.append(line.strip()[:160])
+    assert not pool_ops, "whole-pool copy or write-back:\n" + "\n".join(
+        pool_ops)
+    assert not layer_ops, "whole-layer K/V slice computed:\n" + "\n".join(
+        layer_ops)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < k_pool.size * k_pool.dtype.itemsize / 4, (
+        f"temporaries {temp / 1e9:.3f} GB"
+    )
 
 
 def test_prefill_compiles_for_v5e(qwen):
