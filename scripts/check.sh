@@ -7,7 +7,7 @@ cd "$(dirname "$0")/.."
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m repro.analysis src
 python scripts/check_metrics.py
 if command -v ruff >/dev/null 2>&1; then
-  ruff check src tests benchmarks
+  ruff check src tests
 else
   echo "check.sh: ruff not installed; skipping (CI runs the pinned ruff)" >&2
 fi

@@ -6,8 +6,9 @@
 - length_policy: Long/Medium/Short runtime classification (§4.2.3)
 - verify: lossless speculative verification (greedy + rejection sampling)
 - scheduler: continuous-batching slot pool + LPT admission queue
-- spec_engine: draft → verify → update rollout loop (lock-step batched
-  `generate` and continuous-batching `serve`/`generate_continuous`)
+- spec_engine: draft → verify → update rollout loop (`serve`, and the
+  batch wrapper `generate`: lock-step by default, slot recycling with
+  `slots=n`)
 """
 
 from .budget import (

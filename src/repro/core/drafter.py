@@ -59,13 +59,13 @@ class DrafterConfig:
     # usable match depth is capped at this many tokens. Chosen to equal
     # MatchState's resync_cap, which imposes the same cap on host
     # sessions whenever the tree mutated since their last round — the
-    # continuous-serving regime. In mutation-free stretches (lock-step
-    # generate within one batch) a persistent host session could hold
+    # serving regime, where finished rollouts publish between rounds. In
+    # mutation-free stretches a persistent host session could hold
     # matches deeper than the tail; the device path deliberately trades
     # that tail-risk depth for bounded per-round state (acceptance-only
-    # effect — T=0 verification is lossless either way). The unfused
-    # propose feeds the whole tail every round; the fused round carries
-    # the matcher's registers and feeds only the new tokens, holding the
+    # effect — T=0 verification is lossless either way). ``dispatch``
+    # feeds the whole tail every call; the fused round carries the
+    # matcher's registers and feeds only the new tokens, holding the
     # match to the same cap.
     device_tail: int = 64
     # Packed-forest device layout. "flat" concatenates every tree into
@@ -390,15 +390,6 @@ class BatchedDraftSessions:
         if keys:
             self._refresh_forest(keys)
 
-    def refresh_for(self, rows) -> None:
-        """Refresh packs/forest for the given rows' trees (the fused
-        engine's pre-dispatch hook — version-gated, cheap when warm)."""
-        if not self.device:
-            return
-        keys = {self._keys[b] for b in rows if self._open[b]}
-        if keys:
-            self._refresh_forest(keys)
-
     def forest_arrays(self):
         """Current packed forest for the fused round program. Falls back
         to a cached empty flat forest when no tree is packed yet (cold
@@ -421,21 +412,9 @@ class BatchedDraftSessions:
                 roots[b] = self._roots_by_key.get(self._keys[b], -1)
         return roots
 
-    def tails_matrix(self) -> np.ndarray:
-        """(n_rows, tail_len) left-padded context tails — the one-time
-        host→device seed of the fused round state. Rows fed afterwards
-        by the device shift register go stale here by design."""
-        m = self.tail_len
-        out = np.full((self.n_rows, m), -1, np.int32)
-        for b in range(self.n_rows):
-            cur = int(self._tlen[b])
-            n = min(cur, m)
-            if n:
-                out[b, m - n:] = self._tails[b, cur - n:cur]
-        return out
-
     def tail_row(self, row: int) -> np.ndarray:
-        """(tail_len,) left-padded tail of one row (fused admissions)."""
+        """(tail_len,) left-padded tail of one row: the host→device seed
+        of an admitted row's fused round state."""
         m = self.tail_len
         out = np.full(m, -1, np.int32)
         cur = int(self._tlen[row])
@@ -446,8 +425,8 @@ class BatchedDraftSessions:
 
     def feed_rows(self, rows, cand: np.ndarray, n_take) -> None:
         """Feed each row its accepted tokens ``cand[b, :n_take[b]]`` —
-        the unfused consume path, hoisted out of the engine's round
-        loop."""
+        the host-draft round's consume path, hoisted out of the engine's
+        round loop."""
         for b in rows:
             k = int(n_take[b])
             if k:
@@ -572,7 +551,7 @@ class SuffixDrafter:
         # never change — drafts only gate acceptance.
         self._fb_store = None
         self._fb_index = None
-        # Stats for EXPERIMENTS/benchmarks. Counter-shaped; when an
+        # Drafter counters (sessions, proposes, repacks). Counter-shaped; when an
         # engine attaches telemetry the same writes also feed the
         # registry (``das_drafter_stat_total{key=...}``) — every
         # existing ``stats["k"] += n`` call site is unchanged.

@@ -1,12 +1,12 @@
 """Fused device-resident verify rounds (draft → verify → accept in ONE
 dispatch).
 
-Since the suffix-match kernel landed, both the draft walk and the model
-forward already run on device — yet the unfused engine still round-trips
-the host every round: proposals are materialized to numpy, re-assembled
-into a host block, re-uploaded, and the verify result is synced back
-before the next propose can be built. At production batch that host
-ping-pong, not compute, bounds the round rate.
+The engine's round, whenever its drafter drafts on the device (scopes
+``problem`` and ``global``): the draft walk and the model forward both
+run on device, so nothing of the round needs the host but the budgets
+going in and the emitted tokens coming out. Host per-row draft sessions
+(scope ``problem+request``) keep the split round: host proposals, a
+host-built block, ``verify_step``, a host emit scan.
 
 This module fuses the whole steady-state round into one jitted program
 per (K-bucket, forest geometry):
@@ -28,7 +28,7 @@ the registers that feed left; resumed this way, the same
 ``match_propose_row`` core under the same tail cap reaches the
 registers a feed of the whole tail from the root reaches, so proposals
 — and therefore sampled tokens under a shared PRNG stream — are
-bit-identical to the unfused round. Registers are only good against the
+bit-identical to a whole-tail feed's. Registers are only good against the
 tree they were fed in: the engine resets them (``forget_matches``)
 whenever it uploads a new forest or new roots, and for the rows it
 admits or evicts; a reset row is fed its whole tail from the root.
@@ -36,12 +36,7 @@ admits or evicts; a reset row is fed its whole tail from the root.
 The host uploads one (B,) budget vector per round and downloads one
 packed (B, K+5) result: ``[cand tokens | accepted | n_take | alive |
 n_prop]`` — everything consume-side bookkeeping needs, double-buffered
-by the engine. An optional R-round device micro-loop
-(``micro_rounds > 1``; lock-step ``generate``) reuses the budgets for up
-to R rounds and exits early the moment any row finishes, syncing host
-bookkeeping every R rounds instead of every round (token-identical at
-T=0; at T>0 the per-round PRNG stream is folded on device, so outputs
-stay in-distribution but are not bit-identical to the R=1 stream).
+by the engine.
 """
 
 from __future__ import annotations
@@ -137,10 +132,6 @@ def advance_feed(xp, fed, carried, feed_from, alive, n_take, m):
     return keep, xp.where(keep, nxt, xp.where(waiting, m, 0))
 
 
-# Packed per-round result columns appended after the K+1 cand tokens.
-OUT_EXTRA = 4  # accepted | n_take | alive | n_prop
-
-
 # das: hot-path — shared verify core, traced inside every round dispatch
 def verify_step(
     params, cfg, cache, block, budgets, active, key,
@@ -148,8 +139,8 @@ def verify_step(
 ) -> Tuple[Any, Any]:
     """One verify forward + acceptance + cache commit (traceable).
 
-    Shared by the unfused per-K jitted verify and the fused round
-    program so both paths run the exact same ops (token parity by
+    Shared by the host-draft round's per-K jitted verify and the fused
+    round program so both paths run the exact same ops (token parity by
     construction). Returns (VerifyResult, committed cache).
     """
     B = block.shape[0]
@@ -211,7 +202,7 @@ def fused_round_core(
 ):
     """One fused round (traceable): propose → verify → commit → state.
 
-    Returns (cache', state', out (B, K+1+OUT_EXTRA) i32). ``out`` packs
+    Returns (cache', state', out (B, K+5) i32). ``out`` packs
     everything the host consume path needs into ONE download:
     ``[cand (K+1) | accepted | n_take | alive | n_prop]``. Rows outside
     ``state.active`` carry zeros in the bookkeeping columns and leave
@@ -235,7 +226,7 @@ def fused_round_core(
             # root; rows without budget are fed too, to stay carried.
             # Rows without a packed tree (root < 0) or without budget
             # propose nothing and take a plain AR step — same as the
-            # unfused path.
+            # host-draft round.
             fed = matcher_feeds(state.active, roots >= 0, budgets,
                                 carried, state.feed_from, m)
             first = jnp.where(carried, state.feed_from, 0)
@@ -302,79 +293,30 @@ def fused_round_core(
 
 
 def build_fused_round(
-    cfg, *, K: int, micro_rounds: int, temperature: float, eos_token: int,
-    recurrent: bool, attn_impl: str, min_match: int,
+    cfg, *, K: int, temperature: float, eos_token: int, recurrent: bool,
+    attn_impl: str, min_match: int,
 ):
-    """Jitted fused-round program for one K-bucket.
-
-    Uniform signature for R = 1 and the R-round micro-loop:
+    """Jitted fused-round program for one K-bucket:
 
         fused(params, forest, cache, state, roots, budgets, key)
-          -> (cache', state', outs (R, B, K+1+OUT_EXTRA), n_done)
+          -> (cache', state', out (B, K+5))
 
     ``cache`` and ``state`` are donated — the round is an in-place
-    update of the pool. With ``micro_rounds > 1`` the program iterates
-    up to R rounds in a ``lax.while_loop``, re-clamping budgets against
-    the rows' shrinking remaining-token counts each round, and exits
-    early the moment the active-row composition changes (a finished row
-    needs host bookkeeping: slot recycling, history observation). Only
-    the first ``n_done`` rows of ``outs`` are valid.
+    update of the pool.
     """
-    core = functools.partial(
-        fused_round_core, K=K, temperature=temperature,
-        eos_token=eos_token, recurrent=recurrent, attn_impl=attn_impl,
-        min_match=min_match,
-    )
-    R = max(1, int(micro_rounds))
-
-    if R == 1:
-        @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def fused(params, forest, cache, state, roots, budgets, key):
-            cache2, state2, out = core(
-                params, cfg, forest, cache, state, roots, budgets, key
-            )
-            return cache2, state2, out[None], jnp.ones((), jnp.int32)
-
-        return fused
-
     @functools.partial(jax.jit, donate_argnums=(2, 3))
-    def fused_micro(params, forest, cache, state, roots, budgets, key):
-        B = state.head.shape[0]
-        outs0 = jnp.zeros((R, B, K + 1 + OUT_EXTRA), jnp.int32)
-        active0 = state.active
-
-        def cond(carry):
-            i, _, st, _ = carry
-            return (
-                (i < R)
-                & jnp.any(st.active)
-                & jnp.all(st.active == active0)
-            )
-
-        def body(carry):
-            i, cache_i, st, outs = carry
-            # Budgets are host-solved once per micro-loop; re-clamp
-            # against each round's remaining tokens so a stale budget
-            # can never draft past a row's limit.
-            b_i = jnp.minimum(
-                budgets, jnp.maximum(st.max_new - st.emitted - 1, 0)
-            )
-            kv = jax.random.fold_in(key, i)
-            cache_i, st, out = core(
-                params, cfg, forest, cache_i, st, roots, b_i, kv
-            )
-            return i + 1, cache_i, st, outs.at[i].set(out)
-
-        n_done, cache2, state2, outs = jax.lax.while_loop(
-            cond, body, (jnp.zeros((), jnp.int32), cache, state, outs0)
+    def fused(params, forest, cache, state, roots, budgets, key):
+        return fused_round_core(
+            params, cfg, forest, cache, state, roots, budgets, key,
+            K=K, temperature=temperature, eos_token=eos_token,
+            recurrent=recurrent, attn_impl=attn_impl, min_match=min_match,
         )
-        return cache2, state2, outs, n_done
 
-    return fused_micro
+    return fused
 
 
 def unpack_round_out(out_row: np.ndarray, K: int):
-    """Split one (B, K+1+OUT_EXTRA) host round row into its columns:
+    """Split one (B, K+5) host round result into its columns:
     (cand, accepted, n_take, alive, n_prop)."""
     K1 = K + 1
     return (

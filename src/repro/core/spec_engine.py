@@ -1,36 +1,31 @@
-"""Speculative-decoding rollout engine (paper Fig. 3) — lock-step and
-continuous-batching modes.
+"""Speculative-decoding rollout engine (paper Fig. 3): one round loop,
+``SpecEngine.serve``, with two draft sources.
 
 Host side: the length-aware budget policy (length_policy.py +
-budget.py), per-request output assembly, and rollout statistics.
-Device side — in the default **fused** mode (``EngineConfig.
-fuse_rounds``, core/fused_round.py) — the ENTIRE steady-state round:
-suffix-match propose over the packed forest, verify-block assembly,
-model forward + acceptance, cache commit, EOS/limit emit scan, and the
-next round's session state (heads / context tails / emitted counts
-live on device in a ``RoundState`` between rounds). The host uploads
-one (B,) budget vector per round and downloads one packed per-row
-result, double-buffered. The unfused fallback (``fuse_rounds="off"``,
-or host per-row sessions for the ``problem+request`` scope /
-``device_draft="off"``) keeps the split dispatches: one batched
-draft-proposal call, host block assembly, one verify call, host emit
-scan.
+budget.py), slot admission (scheduler.py), per-request output assembly
+and rollout statistics. Each round the host solves one (B,) budget
+vector; where the drafts come from decides the rest of the round:
 
-Two serving modes share the same stepwise primitives (budget solve →
-round dispatch → vectorized consume):
+* device drafts (drafter scopes ``problem``/``global``) — the ENTIRE
+  round is one jitted program (core/fused_round.py): suffix-match
+  propose over the packed forest, verify-block assembly, model forward +
+  acceptance, cache commit, EOS/limit emit scan, and the next round's
+  session state (heads / context tails / emitted counts live on device
+  in a ``RoundState`` between rounds). The host uploads the budgets and
+  downloads one packed per-row result;
+* host drafts (scope ``problem+request``, whose per-request tree grows
+  on the host) — per-row sessions propose, the host builds the block,
+  one jitted verify (the same ``verify_step``) runs, and the host scans
+  the emitted tokens.
 
-* ``generate``            — lock-step batched rollout: one fixed batch,
-  every row steps together; finished rows ride along as dead padded
-  slots until the stragglers drain (the Fig. 1 batch collapse).
-* ``serve`` / ``generate_continuous`` — continuous batching: a fixed
-  pool of device slots fed from an admission queue ordered
-  longest-predicted-first (scheduler.py). A finished row's slot is
-  immediately re-prefilled with the next pending request (slot
-  recycling keeps the effective batch full through the long tail), and
-  rounds are double-buffered: while the jitted verify for round *t*
-  executes on device, the host observes finished rollouts and pre-solves
-  round *t+1* budgets, materializing ``res.accepted`` only when the next
-  dispatch needs it.
+``serve`` feeds a fixed pool of device slots from an admission queue
+ordered longest-predicted-first. A finished row's slot is re-prefilled
+with the next pending request, and rounds are double-buffered: while
+round *t* executes on device, the host observes finished rollouts and
+pre-solves round *t+1* budgets. ``generate`` is the batch wrapper: with
+one slot per prompt (its default) no slot is ever recycled, every row
+steps together and finished rows ride along idle until the stragglers
+drain — lock-step rollout, the Fig. 1 batch collapse.
 
 The verify block is padded to a *bucketed* size so each bucket compiles
 once: per-row budgets stay ragged (positions past a row's budget are
@@ -40,9 +35,9 @@ keeping XLA shapes static. Latency is accounted with the paper's model
 counts (what a ragged-batching serving engine would execute), plus
 measured wall-clock on this host.
 
-Greedy (T=0) speculative verification is lossless, so both modes emit
-token-identical per-request outputs (continuous-vs-lock-step parity is
-asserted in tests/test_scheduler.py and benchmarks/bench_rollout.py).
+Greedy (T=0) speculative verification is lossless: outputs are
+token-identical to plain decoding whatever the pool size or draft
+source (tests/test_scheduler.py, tests/test_fused_round.py).
 """
 
 from __future__ import annotations
@@ -76,7 +71,7 @@ from repro.core.length_policy import (
 )
 from repro.core.scheduler import CANCELLED, EXPIRED, Request, SlotScheduler
 from repro.obs.flight import NULL_FLIGHT
-from repro.core.verify import sample_token, sample_token_rows, verify_block
+from repro.core.verify import sample_token_rows
 from repro.models import model as M
 
 
@@ -92,43 +87,6 @@ class EngineConfig:
     unlimited_budget: bool = False  # ablation: always max_draft
     attn_impl: str = "xla"
     cache_headroom: int = 64
-    # Batched device drafting (kernels/suffix_match): "auto" uses the
-    # device path whenever the drafter scope supports it (problem /
-    # global; problem+request keeps per-row host sessions), "on"/"off"
-    # force it. One batched propose per round replaces B per-row Python
-    # tree walks; proposals stay host-oracle-identical on the same tail.
-    device_draft: str = "auto"
-    # Fused device-resident rounds (core/fused_round.py): propose →
-    # block build → verify forward → accept → cache commit → next-round
-    # session state, all in ONE jitted dispatch per round. The host
-    # uploads one budget vector and downloads one packed result per
-    # round. "auto" fuses whenever the batched device drafter is active
-    # (see device_draft); "off" keeps the unfused multi-dispatch round
-    # (the config-selectable fallback); "on" forces fusion where the
-    # drafter supports it.
-    fuse_rounds: str = "auto"
-    # R-round device micro-loop for lock-step `generate` (fused mode
-    # only): host budgets/bookkeeping sync every R rounds instead of
-    # every round; the loop exits early the moment any row finishes.
-    # Token-identical at T=0; at T>0 the PRNG fold runs on device, so
-    # R>1 is in-distribution but not bit-identical to the R=1 stream.
-    micro_rounds: int = 1
-
-    def __post_init__(self) -> None:
-        if self.device_draft not in ("auto", "on", "off"):
-            raise ValueError(
-                f"device_draft must be 'auto'|'on'|'off', "
-                f"got {self.device_draft!r}"
-            )
-        if self.fuse_rounds not in ("auto", "on", "off"):
-            raise ValueError(
-                f"fuse_rounds must be 'auto'|'on'|'off', "
-                f"got {self.fuse_rounds!r}"
-            )
-        if self.micro_rounds < 1:
-            raise ValueError(
-                f"micro_rounds must be >= 1, got {self.micro_rounds}"
-            )
 
 
 @dataclass
@@ -140,10 +98,10 @@ class RolloutStats:
     n_drafted: int = 0
     n_accepted: int = 0
     wall_time_s: float = 0.0
-    # Round-path host accounting (benchmarks/bench_rollout.py): host
-    # milliseconds spent on per-round bookkeeping (budget solve, block/
-    # dispatch assembly, consume-side bookkeeping — device waits
-    # excluded) and the number of host↔device array crossings.
+    # Round-path host accounting: host seconds spent on per-round
+    # bookkeeping (budget solve, block/dispatch assembly, consume-side
+    # bookkeeping — device waits excluded) and the number of
+    # host↔device array crossings.
     host_time_s: float = 0.0
     n_h2d: int = 0
     n_d2h: int = 0
@@ -201,15 +159,13 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _prompt_bucket(n: int) -> int:
-    """Prompt pad width (16-multiples). Both serving modes MUST use the
-    same bucketing: compiled prefill variants are keyed on (Tp, max_len)
-    and the lock-step/continuous parity + cache-geometry contract
-    (copy_cache_rows) relies on identical padding."""
+    """Prompt pad width (16-multiples): compiled prefill variants are
+    keyed on (Tp, max_len), so the bucket bounds their number."""
     return max(16, _round_up(n, 16))
 
 
 def _cache_bucket(n: int) -> int:
-    """Cache length rounding (64-multiples), shared for the same reason."""
+    """Cache length rounding (64-multiples), for the same reason."""
     return _round_up(n, 64)
 
 
@@ -322,7 +278,7 @@ class SpecEngine:
         self._recurrent = M.has_recurrent(cfg)
         self._verify_jit: Dict[int, Any] = {}
         self._prefill_jit: Dict[Tuple[int, int], Any] = {}
-        self._fused_jit: Dict[Tuple[int, int], Any] = {}
+        self._fused_jit: Dict[int, Any] = {}
         self._copy_rows_fn = None
         self._admit_state_fn = None
         self._evict_state_fn = None
@@ -441,27 +397,6 @@ class SpecEngine:
                 continue
         return out
 
-    def _note_round_obs(self, budgets, accepted, mask, emitted_before) -> None:
-        """Mirror one verify round into the registry — called only when
-        telemetry is enabled, with the same arrays the RolloutStats
-        bookkeeping just used (no recompute on the hot path)."""
-        mx = self._mx
-        mx["rounds"].inc()
-        mx["fwd"].inc()
-        mx["proposed"].inc(float((1 + budgets[mask]).sum()))
-        mx["drafted"].inc(float(budgets[mask].sum()))
-        mx["accepted"].inc(float(accepted[mask].sum()))
-        lp = self.length_policy
-        hists = self._accept_class_hist
-        by_cls: List[List[float]] = [[], [], []]
-        for b in np.nonzero(mask)[0]:
-            by_cls[lp.classify_length(float(emitted_before[b]))].append(
-                float(accepted[b])
-            )
-        for cls_i, vals in enumerate(by_cls):
-            if vals:
-                hists[cls_i].observe_many(vals)
-
     def _to_device(self, tree):
         """Commit host (or default-device) arrays to the params' device."""
         return jax.device_put(tree, self.device)
@@ -490,7 +425,7 @@ class SpecEngine:
 
     def _get_verify(self, K: int):
         """Jitted verify step for a draft-block bucket of size K (the
-        unfused round's verify dispatch; the fused program traces the
+        host-draft round's verify dispatch; the fused program traces the
         same ``verify_step`` body)."""
         fn = self._verify_jit.get(K)
         if fn is None:
@@ -512,28 +447,23 @@ class SpecEngine:
         return fn
 
     def _get_fused(self, K: int, R: int):
-        """Jitted fused round program for bucket K (micro-loop depth R).
+        """Jitted fused round program for bucket K.
 
         One program per (K-bucket, forest/cache geometry): geometry
-        changes retrace via jax's shape keying, the K bucket and
-        micro-loop depth key this dict."""
-        fn = self._fused_jit.get((K, R))
+        changes retrace via jax's shape keying, the K bucket keys this
+        dict. ``R`` is always 1 (one round per dispatch); it stays in
+        the signature for callers that pass it."""
+        fn = self._fused_jit.get(K)
         if fn is None:
             e = self.engine
             fn = build_fused_round(
-                self.cfg, K=K, micro_rounds=R,
+                self.cfg, K=K,
                 temperature=e.temperature, eos_token=e.eos_token,
                 recurrent=self._recurrent, attn_impl=e.attn_impl,
                 min_match=self.drafter.cfg.min_match,
             )
-            self._fused_jit[(K, R)] = fn
+            self._fused_jit[K] = fn
         return fn
-
-    def _fuse_enabled(self, bds) -> bool:
-        """Fused rounds need the batched device drafter (host per-row
-        sessions — scope problem+request or device_draft=off — keep the
-        unfused loop)."""
-        return bds.device and self.engine.fuse_rounds != "off"
 
     def _get_copy_rows(self):
         """Jitted batched admission write: k freshly prefilled cache
@@ -625,13 +555,6 @@ class SpecEngine:
                 return b
         return self.engine.max_draft
 
-    def _batched_sessions(self, n_rows: int):
-        """Per-round draft state: one batched device propose per round
-        (``EngineConfig.device_draft``), host per-row sessions otherwise."""
-        e = self.engine
-        device = None if e.device_draft == "auto" else e.device_draft == "on"
-        return self.drafter.batched_sessions(n_rows, device=device)
-
     # -- budgets --------------------------------------------------------------
     def _round_budgets(
         self, problem_ids, emitted_lens, active, remaining
@@ -698,408 +621,7 @@ class SpecEngine:
         budgets[idx] = b
         return budgets
 
-    # -- lock-step mode -------------------------------------------------------
-    # das: hot-path — the unfused round loop; every round pays this host code
-    def generate(
-        self,
-        prompts: Sequence[Sequence[int]],
-        problem_ids: Optional[Sequence] = None,
-        *,
-        max_new_tokens=None,
-        key: Optional[jax.Array] = None,
-        collect_effective_batch: bool = False,
-        watchdog=None,
-        journal=None,
-        journal_keys: Optional[Sequence[str]] = None,
-    ) -> Tuple[List[List[int]], RolloutStats]:
-        """Synchronous lock-step batched rollout with DAS speculation.
-
-        ``max_new_tokens`` may be a scalar or a per-row sequence. Returns
-        (generations per row (token lists, EOS-exclusive), stats). This
-        is the baseline mode; ``generate_continuous`` serves the same
-        requests through the slot-recycling pool.
-
-        ``watchdog`` (a ``repro.fault.RolloutWatchdog``) deadlines the
-        round loop: every round checks in, every completed round counts
-        as progress, and a deadline overrun raises ``StallError`` —
-        which the fault-tolerant rollout layer catches to re-queue this
-        worker's problems to survivors.
-
-        ``journal`` (a ``repro.fault.RolloutJournal``) makes in-flight
-        progress crash-durable: each row's accepted tokens buffer as one
-        round record and group-commit once per verify round from the
-        post-consume host window. ``journal_keys`` names the sessions
-        (default ``row{b}``) — pass stable per-rollout keys so recovery
-        can match journaled progress back to its problem. Lock-step mode
-        journals but does not resume; salvaged sessions re-serve through
-        ``serve``'s prefix re-prefill path (token-identical at T=0).
-        """
-        e = self.engine
-        if watchdog is not None:
-            watchdog.arm()
-        t0 = time.perf_counter()
-        B = len(prompts)
-        mn = max_new_tokens if max_new_tokens is not None else e.max_new_tokens
-        max_new_arr = _as_max_new_array(mn, B)
-        if problem_ids is None:
-            problem_ids = list(range(B))
-        if key is None:
-            key = jax.random.key(0)
-        # ---- prefill (left-pad to a bucketed common length to bound the
-        # number of compiled prefill/verify variants) ----
-        Tp = _prompt_bucket(max(len(p) for p in prompts))
-        toks = np.zeros((B, Tp), np.int32)
-        mask = np.zeros((B, Tp), bool)
-        for b, p in enumerate(prompts):
-            toks[b, Tp - len(p):] = p
-            mask[b, Tp - len(p):] = True
-        max_len = _cache_bucket(
-            Tp + int(max_new_arr.max(initial=0)) + e.max_draft + 2
-        )
-        last_logits, cache = self._get_prefill(Tp, max_len)(
-            self.params, toks, mask
-        )
-        key, k0 = jax.random.split(key)
-        head = np.array(  # dascheck: disable=DAS001 -- one-time prefill sample download, before the round loop
-            sample_token(
-                last_logits[:, : self.cfg.vocab_size],
-                temperature=e.temperature, key=k0,
-            )
-        ).astype(np.int32)
-        # ---- draft sessions (batched: one device propose per round) ----
-        bds = self._batched_sessions(B)
-        for b in range(B):
-            bds.open(b, problem_ids[b], list(prompts[b]))
-        outputs: List[List[int]] = [[] for _ in range(B)]
-        active = np.ones(B, bool)
-        emitted = np.zeros(B, np.int64)
-        rounds_per_row = np.zeros(B, np.int64)
-        stats = RolloutStats()
-        # first sampled token counts as emitted output
-        for b in range(B):
-            tok = int(head[b])
-            if tok == e.eos_token or max_new_arr[b] == 0:
-                active[b] = False
-                if max_new_arr[b] > 0:
-                    outputs[b].append(tok)
-            else:
-                outputs[b].append(tok)
-                emitted[b] = 1
-                if max_new_arr[b] <= 1:  # head token already fills the limit
-                    active[b] = False
-                else:
-                    bds.feed(b, [tok])
-        # account the prefill pass
-        stats.n_fwd += 1
-        stats.n_toks_proposed += int(mask.sum())
-
-        # Flight recorder: lock-step rows are one trace each. Traces
-        # mint whenever a journal needs them for continuity or a
-        # recorder is attached; per-round capture is one batched append
-        # from the accept_emit window (same bar as the journal commit).
-        flt = getattr(self.telemetry, "flight", None) or NULL_FLIGHT
-        rec_flight = flt.enabled
-        traces: Optional[List[str]] = None
-        if rec_flight or journal is not None:
-            traces = [flt.new_trace() for _ in range(B)]
-        if rec_flight:
-            for b in range(B):
-                flt.record(traces[b], "admit", rid=b, slot=b, round=0)
-
-        jkeys: Optional[List[str]] = None
-        if journal is not None:
-            jkeys = [
-                str(journal_keys[b]) if journal_keys is not None
-                else f"row{b}" for b in range(B)
-            ]
-            for b in range(B):
-                journal.begin(
-                    jkeys[b], prompts[b], problem_id=problem_ids[b],
-                    max_new_tokens=int(max_new_arr[b]),
-                    trace=traces[b],
-                )
-                if outputs[b]:  # the sampled head token
-                    journal.note(jkeys[b], outputs[b])
-            journal.commit()
-
-        if self._fuse_enabled(bds):
-            cache = self._fused_generate_rounds(
-                bds, cache, key, problem_ids, outputs, active, emitted,
-                max_new_arr, head, rounds_per_row, stats,
-                collect_effective_batch, watchdog=watchdog,
-                journal=journal, jkeys=jkeys, flt=flt, traces=traces,
-            )
-        else:
-            tel = self.telemetry
-            while active.any():
-                if watchdog is not None:
-                    watchdog.check("generate round")
-                host0 = stats.host_time_s
-                with tel.span("round"):
-                    t_h = time.perf_counter()
-                    with tel.span("budget_solve"):
-                        remaining = max_new_arr - emitted
-                        budgets_np = self._round_budgets(
-                            problem_ids, emitted, active, remaining
-                        )
-                    kmax = int(budgets_np.max()) if active.any() else 0
-                    K = self._bucket(kmax)
-                    # ---- drafting: one batched propose for all active
-                    # rows; the device walk overlaps block assembly ----
-                    with tel.span("draft_dispatch"):
-                        prop_handle = bds.dispatch(budgets_np)
-                        block = np.zeros((B, K + 1), np.int32)
-                        block[:, 0] = head
-                        props = bds.consume(prop_handle)
-                        for b in np.nonzero(active)[0]:
-                            prop = props[b]
-                            budgets_np[b] = len(prop)
-                            if prop:
-                                block[b, 1 : 1 + len(prop)] = prop
-                    kv = key
-                    if e.temperature > 0:  # greedy never uses the key
-                        key, kv = jax.random.split(key)
-                    block_dev = jnp.asarray(block)
-                    budgets_dev = jnp.asarray(budgets_np.astype(np.int32))
-                    active_dev = jnp.asarray(active)
-                    stats.host_time_s += time.perf_counter() - t_h
-                    stats.n_h2d += 3  # block + budgets + active uploads
-                    # verify_forward includes the device wait: acceptance
-                    # + cache commit run inside the jitted verify step.
-                    with tel.span("verify_forward") as sp_v:
-                        sp_v.set(h2d=3, d2h=2)
-                        res, cache = self._get_verify(K)(
-                            self.params, cache, block_dev, budgets_dev,
-                            active_dev, kv,
-                        )
-                        accepted = np.asarray(res.accepted).astype(np.int64)  # dascheck: disable=DAS001 -- the unfused round's sanctioned acceptance download
-                        next_tok = np.asarray(res.next_token).astype(np.int32)  # dascheck: disable=DAS001 -- paired with the acceptance download above
-                    stats.n_d2h += 2
-                    # ---- host bookkeeping (vectorized EOS/emit scan) ----
-                    t_h = time.perf_counter()
-                    with tel.span("accept_emit"):
-                        stats.n_rounds += 1
-                        stats.n_fwd += 1
-                        stats.n_toks_proposed += int(
-                            (1 + budgets_np[active]).sum()
-                        )
-                        stats.n_drafted += int(budgets_np[active].sum())
-                        stats.n_accepted += int(accepted[active].sum())
-                        stats.round_accepts.append(
-                            float(accepted[active].mean())
-                            if active.any() else 0.0
-                        )
-                        if collect_effective_batch:
-                            stats.effective_batch.append(int(active.sum()))
-                        if tel.enabled:
-                            self._note_round_obs(
-                                budgets_np, accepted, active, emitted
-                            )
-                        if rec_flight:
-                            rows_f = np.nonzero(active)[0]
-                            flt.record_round(
-                                stats.n_rounds,
-                                [traces[b] for b in rows_f],
-                                accepted[rows_f].tolist(),
-                                budgets_np[rows_f].tolist(),
-                            )
-                        cand = np.zeros((B, K + 1), np.int32)
-                        cand[:, :K] = block[:, 1:]
-                        cand[np.arange(B), accepted] = next_tok
-                        n_take, alive = _emit_scan(
-                            cand, accepted + 1, max_new_arr - emitted,
-                            e.eos_token,
-                        )
-                        alive &= active
-                        for b in np.nonzero(active)[0]:
-                            rounds_per_row[b] += 1
-                            if budgets_np[b] > 0:  # per-prompt telemetry
-                                self.drafter.note_draft(
-                                    problem_ids[b], int(budgets_np[b]),
-                                    int(accepted[b]),
-                                )
-                            take = cand[b, : n_take[b]].tolist()
-                            outputs[b].extend(take)
-                            if journal is not None and take:
-                                journal.note(jkeys[b], take)
-                            if alive[b]:
-                                bds.feed(b, take)
-                            else:
-                                bds.close(b)
-                        emitted[active] += n_take[active]
-                        head = np.where(alive, next_tok, head)
-                        active = alive
-                    if journal is not None:  # post-consume group commit
-                        journal.commit()
-                    if watchdog is not None:
-                        watchdog.progress()
-                    stats.host_time_s += time.perf_counter() - t_h
-                if tel.enabled:
-                    self._mx["round_host"].observe(
-                        stats.host_time_s - host0
-                    )
-        stats.n_h2d += bds.xfers.pop("h2d", 0)
-        stats.n_d2h += bds.xfers.pop("d2h", 0)
-        # strip EOS and observe history
-        for b in range(B):
-            if outputs[b] and outputs[b][-1] == e.eos_token:
-                outputs[b] = outputs[b][:-1]
-            if rec_flight:
-                flt.record(
-                    traces[b], "finish", rid=b, status="finished",
-                    emitted=len(outputs[b]),
-                )
-            self.drafter.observe_rollout(
-                problem_ids[b], list(prompts[b]) + outputs[b], self.epoch,
-                response_len=len(outputs[b]),
-                trace=traces[b] if traces is not None else None,
-            )
-            self.length_policy.observe(problem_ids[b], len(outputs[b]))
-        if journal is not None:
-            for b in range(B):
-                journal.finish(jkeys[b], n_emitted=len(outputs[b]))
-            journal.commit()
-        stats.n_toks_emitted = int(sum(len(o) for o in outputs))
-        stats.per_row_rounds = rounds_per_row
-        stats.per_row_emitted = np.array([len(o) for o in outputs])
-        stats.wall_time_s = time.perf_counter() - t0
-        if self.telemetry.enabled:
-            # transfer counters mirror as one delta per call: a fresh
-            # RolloutStats accumulates them, the registry keeps totals
-            self._mx["h2d"].inc(stats.n_h2d)
-            self._mx["d2h"].inc(stats.n_d2h)
-            self._mx["emitted"].inc(stats.n_toks_emitted)
-        return outputs, stats
-
-    # das: hot-path — fused steady-state round loop (one dispatch per round)
-    def _fused_generate_rounds(
-        self, bds, cache, key, problem_ids, outputs, active, emitted,
-        max_new_arr, head, rounds_per_row, stats, collect_effective_batch,
-        watchdog=None, journal=None, jkeys=None, flt=NULL_FLIGHT,
-        traces=None,
-    ):
-        """Lock-step round loop on the fused device-resident program.
-
-        Per dispatch the host solves budgets, uploads ONE (B,) vector
-        and downloads ONE packed per-row result; head/tails/emitted
-        live on device between rounds (``RoundState``). With
-        ``micro_rounds > 1`` each dispatch runs up to R rounds on
-        device (early-exiting when any row finishes), so host
-        bookkeeping syncs every R rounds. Returns the updated cache.
-        """
-        e = self.engine
-        tel_obs = self.telemetry
-        B = len(outputs)
-        R = int(e.micro_rounds)
-        bds.prewarm()  # pack every open row's tree before round one
-        state = self._to_device(make_state(
-            head, bds.tails_matrix(), active, emitted, max_new_arr
-        ))
-        stats.n_h2d += 5
-        mirror = _MatcherMirror(B, bds.tail_len, *self._matcher_counters)
-        forest = self._to_device(bds.forest_arrays())
-        roots = bds.roots_array()
-        roots_dev = self._to_device(roots)
-        stats.n_h2d += 1
-        last_ver = bds.repack_version
-        while active.any():
-            if watchdog is not None:
-                watchdog.check("fused round")
-            host0 = stats.host_time_s
-            with tel_obs.span("round"):
-                t_h = time.perf_counter()
-                with tel_obs.span("budget_solve"):
-                    remaining = max_new_arr - emitted
-                    budgets_np = self._round_budgets(
-                        problem_ids, emitted, active, remaining
-                    )
-                K = self._bucket(int(budgets_np.max()))
-                with tel_obs.span("forest_refresh"):
-                    rows = np.nonzero(active & (budgets_np > 0))[0]
-                    bds.refresh_for(rows)
-                    if bds.repack_version != last_ver:
-                        last_ver = bds.repack_version
-                        forest = self._to_device(bds.forest_arrays())
-                        roots = bds.roots_array()
-                        roots_dev = self._to_device(roots)
-                        stats.n_h2d += 1
-                        state = self._get_forget_matches()(state)
-                        mirror.forget()
-                kv = key
-                if e.temperature > 0:  # greedy verify never uses the key
-                    key, kv = jax.random.split(key)
-                stats.host_time_s += time.perf_counter() - t_h
-                stats.n_h2d += 1  # the (B,) budget vector
-                # One dispatch = propose → verify → accept → cache
-                # commit → emit scan, all device-side (R micro-rounds).
-                with tel_obs.span("fused_dispatch") as sp_f:
-                    sp_f.set(h2d=1, d2h=2)
-                    cache, state, outs_dev, ndone_dev = self._get_fused(
-                        K, R
-                    )(
-                        self.params, forest, cache, state, roots_dev,
-                        budgets_np.astype(np.int32), kv,
-                    )
-                    outs = np.asarray(outs_dev)  # dascheck: disable=DAS001 -- the fused micro-loop's one download per R rounds
-                    n_done = int(ndone_dev)
-                stats.n_d2h += 2
-                if K > 0 and len(rows) > 0:  # each micro-round proposed
-                    self.drafter.stats["batched_proposes"] += n_done
-                t_h = time.perf_counter()
-                with tel_obs.span("accept_emit"):
-                    for r in range(n_done):
-                        cand, acc, n_take, alive, n_prop = unpack_round_out(
-                            outs[r], K
-                        )
-                        mask = active.copy()
-                        mirror.advance(
-                            mirror.feeds(K, mask, roots, budgets_np),
-                            alive & mask, n_take,
-                        )
-                        stats.n_rounds += 1
-                        stats.n_fwd += 1
-                        stats.n_toks_proposed += int((1 + n_prop[mask]).sum())
-                        stats.n_drafted += int(n_prop[mask].sum())
-                        stats.n_accepted += int(acc[mask].sum())
-                        stats.round_accepts.append(
-                            float(acc[mask].mean()) if mask.any() else 0.0
-                        )
-                        if collect_effective_batch:
-                            stats.effective_batch.append(int(mask.sum()))
-                        if tel_obs.enabled:
-                            self._note_round_obs(n_prop, acc, mask, emitted)
-                        if flt.enabled:
-                            rows_f = np.nonzero(mask)[0]
-                            flt.record_round(
-                                stats.n_rounds,
-                                [traces[b] for b in rows_f],
-                                acc[rows_f].tolist(),
-                                n_prop[rows_f].tolist(),
-                            )
-                        rounds_per_row[mask] += 1
-                        tel = np.nonzero(mask & (n_prop > 0))[0]
-                        if tel.size:  # per-prompt accept telemetry
-                            self.drafter.note_draft_rows(
-                                [problem_ids[b] for b in tel], n_prop[tel],
-                                acc[tel],
-                            )
-                        for b in np.nonzero(mask & (n_take > 0))[0]:
-                            take = cand[b, : n_take[b]].tolist()
-                            outputs[b].extend(take)
-                            if journal is not None:
-                                journal.note(jkeys[b], take)
-                        emitted[mask] += n_take[mask]
-                        active &= alive
-                if journal is not None:  # one group commit per dispatch
-                    journal.commit()
-                if watchdog is not None:
-                    watchdog.progress()
-                stats.host_time_s += time.perf_counter() - t_h
-            if tel_obs.enabled:
-                self._mx["round_host"].observe(stats.host_time_s - host0)
-        return cache
-
-    # -- continuous-batching mode --------------------------------------------
+    # -- the round loop -------------------------------------------------------
     # das: hot-path — the serving round loop; admit/dispatch/consume nested
     # below inherit the marker
     def serve(
@@ -1116,7 +638,7 @@ class SpecEngine:
         preemption=None,
         clock=None,
     ) -> Iterator[Request]:
-        """Continuous-batching serve loop (generator of finished requests).
+        """The engine's round loop (generator of finished requests).
 
         A fixed pool of ``slots`` device slots is fed from an admission
         queue ordered longest-predicted-first (``SlotScheduler``). The
@@ -1132,20 +654,21 @@ class SpecEngine:
         suffix trees for the device drafter (``bds.prewarm``), and (b)
         pre-solves round *t+1* budgets from bounded-staleness emitted
         counts (re-clamped against fresh limits before dispatch).
-        ``res.accepted`` is only materialized when the next dispatch
-        actually needs the head tokens, so the device verify overlaps
-        all of that host work. The round's batched draft propose is
-        itself dispatched before slot admissions, overlapping the
-        device suffix walk with the admissions' B=1 prefills (rows
-        admitted in round *t* draft from round *t+1* on).
+        The round's result is only materialized when the next dispatch
+        needs it, so the device round overlaps all of that host work.
+        Rows admitted in round *t* draft from round *t+1* on.
 
-        Greedy verification is lossless, so per-request outputs are
-        token-identical to ``generate`` at temperature 0.
+        The drafter decides the round (``bds.device``): device drafts
+        run the whole round as one fused dispatch with per-slot session
+        state on device; host per-row sessions (scope ``problem+request``)
+        propose on the host before a jitted verify. Greedy verification
+        is lossless, so per-request outputs are token-identical to plain
+        decoding at temperature 0, whatever the pool size.
 
         ``stats`` counters (rounds, forwards, drafted/accepted, emitted
         tokens, wall time) aggregate across the serve; the per-row
-        arrays are request-order views that only the
-        ``generate_continuous`` wrapper fills.
+        arrays are request-order views that only the ``generate``
+        wrapper fills.
 
         Durability / lifecycle (all optional, all off by default):
 
@@ -1237,10 +760,10 @@ class SpecEngine:
         max_new_arr = np.ones(n_slots, np.int64)
         active = np.zeros(n_slots, bool)
         pids: List[Any] = [None] * n_slots
-        bds = self._batched_sessions(n_slots)
-        fused = self._fuse_enabled(bds)
+        bds = self.drafter.batched_sessions(n_slots)
+        fused = bds.device
 
-        # Fused mode: per-slot session state (head / context tails /
+        # Device drafts: per-slot session state (head / context tails /
         # emitted / limits) lives on DEVICE between rounds; the host
         # mirrors above only drive budget solving and bookkeeping.
         state = None
@@ -1515,14 +1038,14 @@ class SpecEngine:
                 stats.n_d2h += 1
                 t_h = time.perf_counter()
                 cand, accepted, n_take, alive, budgets = unpack_round_out(
-                    outs[0], K
+                    outs, K
                 )
                 alive = alive & mask
                 mirror.advance(fed, alive, n_take)
             else:
                 _, res, block, budgets, mask = pending
                 pending = None
-                accepted = np.asarray(res.accepted).astype(np.int64)  # dascheck: disable=DAS001 -- the unfused round's sanctioned acceptance download
+                accepted = np.asarray(res.accepted).astype(np.int64)  # dascheck: disable=DAS001 -- the host-draft round's sanctioned acceptance download
                 next_tok = np.asarray(res.next_token).astype(np.int32)  # dascheck: disable=DAS001 -- paired with the acceptance download above
                 stats.n_d2h += 2
                 t_h = time.perf_counter()
@@ -1803,7 +1326,7 @@ class SpecEngine:
                 stats.host_time_s += time.perf_counter() - t_h
                 stats.n_h2d += 1  # the (B,) budget vector
                 fed = mirror.feeds(K, active, roots, budgets)
-                cache, state, outs_dev, _ = self._get_fused(K, 1)(
+                cache, state, outs_dev = self._get_fused(K, 1)(
                     self.params, forest, cache, state, roots_dev,
                     budgets.astype(np.int32), kv,
                 )
@@ -1887,13 +1410,12 @@ class SpecEngine:
                     stats.host_time_s += time.perf_counter() - t_h
                 service_lifecycle()
                 draining = drain is not None and drain.draining
-                # ---- unfused: batched draft propose for the rows that
-                # survived the round, dispatched BEFORE admissions so
-                # the device suffix walk overlaps the admission
-                # prefills. Fused: the propose runs inside the round
-                # dispatch below. Either way, rows admitted below draft
-                # from their next round on (one draft-free warmup round
-                # per admission).
+                # ---- host drafts: the per-row sessions propose for the
+                # rows that survived the round, before admissions. Device
+                # drafts: the propose runs inside the round dispatch
+                # below. Either way, rows admitted below draft from their
+                # next round on (one draft-free warmup round per
+                # admission).
                 budgets = prop_handle = None
                 if active.any():
                     t_h = time.perf_counter()
@@ -1959,7 +1481,7 @@ class SpecEngine:
         )
         self.length_policy.observe(req.problem_id, len(req.output))
 
-    def generate_continuous(
+    def generate(
         self,
         prompts: Sequence[Sequence[int]],
         problem_ids: Optional[Sequence] = None,
@@ -1973,51 +1495,47 @@ class SpecEngine:
         journal_keys: Optional[Sequence[str]] = None,
         resume: Optional[Dict[str, Any]] = None,
     ) -> Tuple[List[List[int]], RolloutStats]:
-        """Drop-in for ``generate`` backed by the continuous engine.
+        """Roll out a batch through ``serve``; returns (outputs in prompt
+        order, EOS-exclusive token lists; stats).
 
-        Streams the batch through a pool of ``slots`` device slots
-        (default: one per request — pure recycling of early-finishers'
-        slots requires ``slots < len(prompts)`` to show). Returns
-        outputs in request order plus the usual stats; ``n_rounds`` is
-        the pool makespan in verify rounds.
+        ``slots=None`` gives every prompt its own slot: lock-step, where
+        finished rows ride along idle until the stragglers drain. Fewer
+        ``slots`` recycle early finishers' slots. ``max_new_tokens`` is a
+        scalar or one limit per prompt; ``n_rounds`` is the makespan in
+        verify rounds.
 
-        ``journal``/``journal_keys`` thread the write-ahead token
-        journal through ``serve`` (see there). ``resume`` maps journal
-        keys to salvaged progress — a ``JournalSession`` or a plain
-        token list — from a dead worker's journal; matching rows
-        re-admit via prefix re-prefill instead of regenerating, and
-        rows whose salvage already finished return without any device
-        work.
+        ``watchdog``/``journal`` pass through to ``serve``;
+        ``journal_keys`` names the rows' journal sessions (default the
+        row index). ``resume`` maps journal keys to salvaged progress (a
+        ``JournalSession`` or a token list): those rows re-admit by
+        prefix re-prefill, and rows whose salvage already finished
+        return without device work.
         """
         t0 = time.perf_counter()
         B = len(prompts)
         if problem_ids is None:
             problem_ids = list(range(B))
-        mn = max_new_tokens if max_new_tokens is not None \
-            else self.engine.max_new_tokens
-        max_new_arr = _as_max_new_array(mn, B)
+        max_new_arr = _as_max_new_array(
+            self.engine.max_new_tokens if max_new_tokens is None
+            else max_new_tokens, B)
         reqs = [
             Request(
                 rid=i, problem_id=problem_ids[i], prompt=list(prompts[i]),
                 max_new_tokens=int(max_new_arr[i]),
+                journal_key=(None if journal_keys is None
+                             else str(journal_keys[i])),
             )
             for i in range(B)
         ]
-        if journal_keys is not None:
-            for i, r in enumerate(reqs):
-                r.journal_key = str(journal_keys[i])
         to_serve = reqs
         if resume:
             from repro.fault.journal import JournalSession, resume_requests
 
-            sessions = {
-                str(k): (
-                    v if isinstance(v, JournalSession)
-                    else JournalSession(key=str(k), tokens=list(v))
-                )
+            to_serve, pre_done = resume_requests(reqs, {
+                str(k): v if isinstance(v, JournalSession)
+                else JournalSession(key=str(k), tokens=list(v))
                 for k, v in resume.items()
-            }
-            to_serve, pre_done = resume_requests(reqs, sessions)
+            })
             if pre_done and self.telemetry.enabled:
                 self.telemetry.emit(
                     "resume", pre_done=len(pre_done),

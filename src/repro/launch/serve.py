@@ -110,7 +110,7 @@ def load_model(arch: str, *, smoke: bool, seed: int = 0):
 
 
 def make_engine(params, cfg, *, scope: str = "problem", spec: bool = True,
-                fuse: str = "auto", remote=None, telemetry=None):
+                remote=None, telemetry=None):
     """The serving engine: greedy verification of up to 8 drafted tokens
     per round (K buckets 0/4/8) from a suffix-tree drafter. ``spec=False``
     is plain decoding through the same engine, the reference that
@@ -122,8 +122,7 @@ def make_engine(params, cfg, *, scope: str = "problem", spec: bool = True,
     return SpecEngine(
         params, cfg,
         EngineConfig(spec_enabled=spec, max_new_tokens=32, eos_token=1,
-                     max_draft=8, block_buckets=(0, 4, 8),
-                     fuse_rounds=fuse),
+                     max_draft=8, block_buckets=(0, 4, 8)),
         drafter=SuffixDrafter(
             DrafterConfig(scope=scope, min_match=2), remote=remote
         ),
@@ -180,7 +179,7 @@ def serve_epoch(eng, reqs, *, slots: int, key, watchdog=None, journal=None,
 
 
 def make_workers(params, cfg, book, *, n_workers: int, scope: str = "problem",
-                 fuse: str = "auto", telemetries=None):
+                 telemetries=None):
     """One engine per worker, each with a drafter backed by the history
     service at ``book``. Worker ``w`` serves from ``jax.devices()[w]``
     (round-robin when there are fewer devices): its params copy and
@@ -199,7 +198,7 @@ def make_workers(params, cfg, book, *, n_workers: int, scope: str = "problem",
             client.attach_telemetry(tel)
         engines.append(make_engine(
             jax.device_put(params, devs[w % len(devs)]), cfg, scope=scope,
-            fuse=fuse, remote=client, telemetry=tel,
+            remote=client, telemetry=tel,
         ))
         clients.append(client)
     return engines, clients
@@ -308,15 +307,11 @@ def main() -> None:
                     help="requests per round in continuous and "
                          f"multi-worker mode, in groups of {GROUP} "
                          "(default: 2x --batch)")
-    ap.add_argument("--fuse", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="fused device-resident rounds (one dispatch "
-                         "per verify round); 'off' keeps the unfused "
-                         "multi-dispatch fallback")
     ap.add_argument("--scope", default="problem",
                     choices=["problem", "problem+request", "global"],
-                    help="drafter scope (fused rounds need a tree-only "
-                         "scope: problem or global)")
+                    help="drafter scope: problem or global draft on "
+                         "the device in one fused round; problem+request "
+                         "drafts from host sessions")
     ap.add_argument("--history-dir", default="",
                     help="load persisted rollout history (warm trees + "
                          "warm length priors) from this directory")
@@ -393,8 +388,7 @@ def main() -> None:
         _serve_with_service(args, cfg, params)
         return
     tel, metrics_server = _make_telemetry(args)
-    eng = make_engine(params, cfg, scope=args.scope, fuse=args.fuse,
-                      telemetry=tel)
+    eng = make_engine(params, cfg, scope=args.scope, telemetry=tel)
     if args.history_dir:
         import os
 
@@ -565,7 +559,7 @@ def _serve_with_service(args, cfg, params) -> None:
     # address to every client without reconstructing them.
     engines, clients = make_workers(
         params, cfg, svc.book, n_workers=args.workers, scope=args.scope,
-        fuse=args.fuse, telemetries=tels,
+        telemetries=tels,
     )
     watchdogs = None
     if args.watchdog_deadline > 0:

@@ -56,16 +56,11 @@ SPAN_PHASE = {
     "prefill": "prefill",
     "admission_coalesce": "prefill",
     "cache_commit": "prefill",
-    "verify_forward": "verify",
     "verify_dispatch": "verify",
-    "fused_dispatch": "verify",
     "budget_solve": "draft_host",
-    "draft_dispatch": "draft_host",
-    "forest_refresh": "draft_host",
     "history_sync": "draft_host",
     "history_publish": "draft_host",
     "consume": "accept_consume",
-    "accept_emit": "accept_consume",
 }
 
 CLASS_NAMES = ("short", "medium", "long")

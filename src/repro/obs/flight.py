@@ -19,8 +19,7 @@ round loop (collect hooks, exports, end of serve). The per-verify-round
 accept counts for the whole pool land as a single **batched** raw
 record per round (:meth:`record_round`) and explode into per-trace
 ``round`` events only at drain time, so the round loop pays one append
-regardless of pool size — the same ≤2 % bar as the journal's group
-commit (asserted in ``benchmarks/bench_obs.py``).
+regardless of pool size.
 
 Trace IDs propagate across processes as opaque strings: the journal's
 ``begin`` records carry them (a resumed session continues the SAME

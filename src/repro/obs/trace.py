@@ -1,15 +1,17 @@
 """Span tracer for the rollout round pipeline.
 
 A span is a named host-side wall-time interval.  Spans nest via a
-per-thread stack, so the fused round shows up as
+per-thread stack, so a round of ``SpecEngine.serve`` shows up as
 
-    round
+    serve_round
+    ├─ history_publish
+    ├─ history_sync
     ├─ budget_solve
-    ├─ fused_dispatch
-    └─ accept_emit
+    ├─ consume
+    ├─ admission_coalesce → prefill → cache_commit
+    └─ verify_dispatch
 
-and the unfused round as ``round → budget_solve / draft_dispatch /
-verify_forward / accept_emit``.  Each finished span is observed into
+Each finished span is observed into
 the ``das_phase_seconds{phase=...}`` histogram family (per-phase
 latency distributions for Prometheus) and kept in a bounded ring of
 recent spans (for tests and ``/metrics.json``).

@@ -107,9 +107,10 @@ class RolloutWorker:
         ``resume`` maps journal keys (``"{pid}#{g}"``) to salvaged
         sessions from a failed worker's journal: matching rows re-admit
         via the engine's prefix re-prefill (token-identical at T=0)
-        instead of regenerating from token zero. Resume always routes
-        through the continuous engine — lock-step parity at T=0 makes
-        the outputs indistinguishable.
+        instead of regenerating from token zero. A resumed rollout
+        always takes the worker's slot pool (``slots``), as
+        ``continuous=True`` does; at T=0 the outputs are the same as
+        lock-step's.
         """
         t0 = time.perf_counter()
         prompts, pids, probs, jkeys = [], [], [], []
@@ -119,21 +120,14 @@ class RolloutWorker:
                 pids.append(p.pid)
                 probs.append(p)
                 jkeys.append(f"{p.pid}#{g}")
-        if self.continuous or resume:
-            outs, stats = self.engine.generate_continuous(
-                prompts, pids, slots=self.slots,
-                max_new_tokens=max_new_tokens, key=key,
-                collect_effective_batch=collect_effective_batch,
-                watchdog=self.watchdog, journal=self.journal,
-                journal_keys=jkeys, resume=resume,
-            )
-        else:
-            outs, stats = self.engine.generate(
-                prompts, pids, max_new_tokens=max_new_tokens, key=key,
-                collect_effective_batch=collect_effective_batch,
-                watchdog=self.watchdog, journal=self.journal,
-                journal_keys=jkeys,
-            )
+        outs, stats = self.engine.generate(
+            prompts, pids,
+            slots=self.slots if self.continuous or resume else None,
+            max_new_tokens=max_new_tokens, key=key,
+            collect_effective_batch=collect_effective_batch,
+            watchdog=self.watchdog, journal=self.journal,
+            journal_keys=jkeys, resume=resume,
+        )
         gen_time = time.perf_counter() - t0
         rewards = np.array(
             [self.task.reward(pr, o) for pr, o in zip(probs, outs)],

@@ -31,6 +31,17 @@ def make_params(cfg: ModelConfig, seed: int = 0):
     return params
 
 
+def host_drafts(eng):
+    """``eng``, its drafter handing the round per-row host sessions: the
+    same ``problem``-scope drafts through the host-draft round (host
+    proposals, host-built block, jitted ``verify_step``, host emit scan)
+    instead of the fused one. The reference the fused round is held to."""
+    sessions = eng.drafter.batched_sessions
+    eng.drafter.batched_sessions = (
+        lambda n_rows, device=None: sessions(n_rows, device=False))
+    return eng
+
+
 def hypothesis_or_stub():
     """Return ``(given, settings, st)`` — real hypothesis when installed,
     otherwise stand-ins whose ``given`` marks the decorated property-based

@@ -19,7 +19,7 @@ import pytest
 
 import jax
 
-from conftest import make_params
+from conftest import host_drafts, make_params
 from repro.core.scheduler import (
     CANCELLED,
     EXPIRED,
@@ -291,8 +291,8 @@ class TestServeDurability:
     def test_preempt_resume_parity_unfused(self, tiny_dense,
                                            served_baseline):
         params, base = served_baseline
-        eng = SpecEngine(
-            params, tiny_dense, EngineConfig(fuse_rounds="off", **ECFG)
+        eng = host_drafts(
+            SpecEngine(params, tiny_dense, EngineConfig(**ECFG))
         )
         reqs = _mk_requests()
         out = _serve(eng, reqs, slots=2,

@@ -585,11 +585,11 @@ def _synthetic_fleet(t0=1000.0):
         evs.append(ev(w if i != 3 else "w0", tr, "finish", end,
                       status="finished", emitted=length))
     spans = [
-        {"name": "verify_forward", "parent": "round", "depth": 1,
+        {"name": "verify_dispatch", "parent": "serve_round", "depth": 1,
          "t0": 1.0, "dur_s": 0.6},
-        {"name": "budget_solve", "parent": "round", "depth": 1,
+        {"name": "budget_solve", "parent": "serve_round", "depth": 1,
          "t0": 2.0, "dur_s": 0.2},
-        {"name": "consume", "parent": "round", "depth": 1,
+        {"name": "consume", "parent": "serve_round", "depth": 1,
          "t0": 3.0, "dur_s": 0.2},
         {"name": "prefill", "parent": None, "depth": 0,
          "t0": 0.0, "dur_s": 0.3},

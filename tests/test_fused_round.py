@@ -1,18 +1,19 @@
-"""Fused device-resident rounds vs the unfused multi-dispatch round.
+"""Fused device-resident rounds vs the host-draft round.
 
 The contract under test: with a shared PRNG stream, the fused program
 (propose → block build → verify → commit → state update in ONE dispatch,
-``core/fused_round.py``) emits *bit-identical* tokens to the unfused
-round at temperature 0 AND under seeded sampling — in both serving
-modes — and steady-state serving never triggers a fresh jit compile
-after warmup.
+``core/fused_round.py``) emits *bit-identical* tokens to the round that
+drafts the same ``problem``-scope trees through per-row host sessions
+(``conftest.host_drafts``) at temperature 0 AND under seeded sampling —
+lock-step and with slot recycling — and steady-state serving never
+triggers a fresh jit compile after warmup.
 """
 
 import jax
 import numpy as np
 import pytest
 
-from conftest import make_params
+from conftest import host_drafts, make_params
 from repro.configs.base import ModelConfig
 from repro.core.drafter import DrafterConfig, SuffixDrafter
 from repro.core.fused_round import emit_scan_device
@@ -31,25 +32,26 @@ PIDS = ["a", "b", "c", "d", "e", "a", "b", "c"]
 LIMITS = [14, 9, 22, 7, 5, 11, 3, 18]
 
 
-def _engine(params, cfg, *, fuse, temperature=0.0, micro_rounds=1,
-            device_draft="on", window_size=16):
-    return SpecEngine(
+def _engine(params, cfg, *, host=False, temperature=0.0, window_size=16):
+    """Fused rounds, or with ``host`` the host-draft round on the same
+    drafts."""
+    eng = SpecEngine(
         params, cfg,
         EngineConfig(
             max_new_tokens=24, max_draft=4, block_buckets=(0, 2, 4),
             eos_token=1, temperature=temperature,
-            device_draft=device_draft, fuse_rounds=fuse,
-            micro_rounds=micro_rounds,
         ),
         drafter=SuffixDrafter(
             DrafterConfig(scope="problem", min_match=1,
                           window_size=window_size)
         ),
     )
+    return host_drafts(eng) if host else eng
 
 
 def _two_epochs(eng, *, mode, key0=5, key1=7):
-    """Epoch 0 lock-step (builds history), epoch 1 in ``mode``; returns
+    """Epoch 0 lock-step (builds history), epoch 1 lock-step
+    (``generate``) or through 3 recycled slots (``continuous``); returns
     (epoch-0 outputs, epoch-1 outputs, epoch-1 stats)."""
     eng.begin_iteration(0)
     o0, _ = eng.generate(PROMPTS, PIDS, max_new_tokens=LIMITS,
@@ -59,7 +61,7 @@ def _two_epochs(eng, *, mode, key0=5, key1=7):
         o1, st = eng.generate(PROMPTS, PIDS, max_new_tokens=LIMITS,
                               key=jax.random.key(key1))
     else:
-        o1, st = eng.generate_continuous(
+        o1, st = eng.generate(
             PROMPTS, PIDS, slots=3, max_new_tokens=LIMITS,
             key=jax.random.key(key1),
         )
@@ -68,13 +70,14 @@ def _two_epochs(eng, *, mode, key0=5, key1=7):
 
 @pytest.mark.parametrize("mode", ["generate", "continuous"])
 def test_fused_token_identity_greedy(mode):
-    """T=0: fused rounds must be token-identical to the unfused path in
-    both serving modes (warm drafter, real proposals in flight)."""
+    """T=0: fused rounds must be token-identical to the host-draft round
+    lock-step and with slot recycling (warm drafter, real proposals in
+    flight)."""
     params = make_params(DENSE)
     runs = {}
     for fuse in ("on", "off"):
         runs[fuse] = _two_epochs(
-            _engine(params, DENSE, fuse=fuse), mode=mode
+            _engine(params, DENSE, host=fuse == "off"), mode=mode
         )
     assert runs["on"][0] == runs["off"][0]
     assert runs["on"][1] == runs["off"][1]
@@ -85,38 +88,24 @@ def test_fused_token_identity_greedy(mode):
 @pytest.mark.parametrize("mode", ["generate", "continuous"])
 def test_fused_token_identity_seeded_sampling(mode):
     """T>0 with a fixed seed: the fused path consumes the PRNG stream
-    exactly like the unfused path (per-round verify keys, per-request
-    admission keys), so sampled outputs are bit-identical too."""
+    exactly like the host-draft round (per-round verify keys,
+    per-request admission keys), so sampled outputs are bit-identical
+    too."""
     params = make_params(DENSE)
     runs = {}
     for fuse in ("on", "off"):
         runs[fuse] = _two_epochs(
-            _engine(params, DENSE, fuse=fuse, temperature=0.8), mode=mode
+            _engine(params, DENSE, host=fuse == "off", temperature=0.8),
+            mode=mode,
         )
     assert runs["on"][0] == runs["off"][0]
     assert runs["on"][1] == runs["off"][1]
 
 
-def test_fused_micro_loop_token_identity_and_fewer_syncs():
-    """R>1 lock-step micro-loop: still token-identical at T=0, while the
-    host materializes strictly fewer round results (bookkeeping syncs
-    every R rounds instead of every round)."""
-    params = make_params(DENSE)
-    o_ref, o1_ref, st_ref = _two_epochs(
-        _engine(params, DENSE, fuse="on"), mode="generate"
-    )
-    o_mic, o1_mic, st_mic = _two_epochs(
-        _engine(params, DENSE, fuse="on", micro_rounds=4), mode="generate"
-    )
-    assert (o_ref, o1_ref) == (o_mic, o1_mic)
-    assert st_mic.n_rounds == st_ref.n_rounds  # same verify rounds…
-    assert st_mic.n_d2h < st_ref.n_d2h  # …fewer host syncs
-
-
 def test_fused_ssm_family_runs_and_matches():
     """The fused program composes the staged-state recurrent commit
-    (collect_states + commit_staged_cache) exactly like the unfused
-    verify."""
+    (collect_states + commit_staged_cache) exactly like the host-draft
+    round's verify."""
     cfg = ModelConfig(
         name="t-ssm", family="ssm", block_pattern=("mlstm", "slstm"),
         **{**BASE, "d_ff": 0, "rnn_width": 64},
@@ -125,7 +114,7 @@ def test_fused_ssm_family_runs_and_matches():
     runs = {}
     for fuse in ("on", "off"):
         runs[fuse] = _two_epochs(
-            _engine(params, cfg, fuse=fuse), mode="generate"
+            _engine(params, cfg, host=fuse == "off"), mode="generate"
         )
     assert runs["on"][1] == runs["off"][1]
 
@@ -166,8 +155,9 @@ def test_fused_carried_matcher_serve_parity_and_feed_counts(tail):
     """Serving with admissions mid-stream, forest repacks between rounds
     and an eviction: the fused round, whose matcher carries its
     registers across rounds, stays round-for-round identical to the
-    unfused round (a tail of 8 makes copied text outgrow it). The
-    host's ``das_matcher_*`` counters equal what the device state each
+    fused round that feeds every row its whole tail from the root each
+    round (a tail of 8 makes copied text outgrow it), and, where the
+    tail holds every context, to the host-draft round. The host's ``das_matcher_*`` counters equal what the device state each
     round starts from says, and a round feeds rows in full exactly when
     a new forest or new roots were uploaded since the last round that
     fed: admitted rows wait for that sync unfed, evicted rows leave."""
@@ -177,23 +167,39 @@ def test_fused_carried_matcher_serve_parity_and_feed_counts(tail):
 
     params = make_params(DENSE)
     runs = {}
-    for fuse in ("on", "off"):
+    covers = tail > max(map(len, PROMPTS)) + 1 + 24  # every context fits
+    for fuse in ("on", "whole", "host") if covers else ("on", "whole"):
         tel = obs.Telemetry()
         eng = SpecEngine(
             params, DENSE,
             EngineConfig(max_new_tokens=24, max_draft=4,
-                         block_buckets=(0, 2, 4), eos_token=1,
-                         device_draft="on", fuse_rounds=fuse),
+                         block_buckets=(0, 2, 4), eos_token=1),
             drafter=SuffixDrafter(DrafterConfig(
                 scope="problem", min_match=1, window_size=16,
                 device_tail=tail)),
             telemetry=tel,
         )
+        if fuse == "host":
+            host_drafts(eng)
         eng.begin_iteration(0)
         eng.generate(PROMPTS, PIDS, max_new_tokens=LIMITS,
                      key=jax.random.key(5))
         eng.begin_iteration(1)
         rounds, events = [], []
+        base = _matcher_values(tel)  # epoch 0's feeds
+        if fuse == "whole":
+            get_fused, get_forget = eng._get_fused, eng._get_forget_matches
+
+            def whole(K, R, get_fused=get_fused, get_forget=get_forget):
+                fn = get_fused(K, R)
+
+                def call(params, forest, cache, state, *rest):
+                    return fn(params, forest, cache, get_forget()(state),
+                              *rest)
+
+                return call
+
+            eng._get_fused = whole
         if fuse == "on":
             get_fused, get_forget = eng._get_fused, eng._get_forget_matches
 
@@ -224,22 +230,23 @@ def test_fused_carried_matcher_serve_parity_and_feed_counts(tail):
             eng._get_fused, eng._get_forget_matches = fused, forget
         stats = RolloutStats()
         runs[fuse] = (_serve_with_cancel(eng, stats), stats, rounds, events,
-                      eng)
-    (o_on, st_on, rounds, events, eng), (o_off, st_off, *_) = (
-        runs["on"], runs["off"])
-    assert o_on == o_off
-    assert st_on.n_drafted == st_off.n_drafted > 0
-    assert st_on.n_accepted == st_off.n_accepted
-    assert st_on.round_accepts == st_off.round_accepts
+                      eng, base)
+    o_on, st_on, rounds, events, eng, base = runs["on"]
+    for ref in runs:
+        o_ref, st_ref = runs[ref][:2]
+        assert o_on == o_ref, ref
+        assert st_on.n_drafted == st_ref.n_drafted > 0, ref
+        assert st_on.n_accepted == st_ref.n_accepted, ref
+        assert st_on.round_accepts == st_ref.round_accepts, ref
     assert eng._evict_state_fn is not None  # the cancel evicted a row
     # host counters, round by round, equal the device state's truth
-    prev = (0.0, 0.0, 0.0)
+    prev = base
     for K, n_carried, n_full, vals in rounds:
         assert vals[0] - prev[0] == n_carried
         assert vals[1] - prev[1] == n_full
         assert vals[2] - prev[2] == (n_full > 0)
         prev = vals
-    assert prev[0] > 0 and prev[1] > 0
+    assert prev[0] > base[0] and prev[1] > base[1]
     # full rounds: exactly the rounds that feed after a sync
     full, synced, after_sync = [], False, []
     it = iter(rounds)
@@ -268,7 +275,7 @@ def test_fused_respects_exact_limits_and_head_only_rows():
     limits = [1, 2, 7, 1, 3, 5, 1, 4]
     outs = {}
     for fuse in ("on", "off"):
-        eng = _engine(params, DENSE, fuse=fuse)
+        eng = _engine(params, DENSE, host=fuse == "off")
         outs[fuse], _ = eng.generate(
             PROMPTS, PIDS, max_new_tokens=limits, key=jax.random.key(4)
         )
@@ -294,12 +301,12 @@ def test_emit_scan_device_matches_host():
         assert np.array_equal(h_alive, np.asarray(d_alive))
 
 
-@pytest.mark.parametrize("device_draft", ["on", "off"])
-def test_steady_state_serve_never_recompiles(device_draft):
+@pytest.mark.parametrize("device", ["on", "off"])
+def test_steady_state_serve_never_recompiles(device):
     """Recompile guard: after a warmup serving epoch over mixed-length
     requests, further epochs of the same workload must trigger ZERO new
-    jit compilations — in the fused device-draft mode and in the host
-    fallback mode alike. (RL training serves the same problem set every
+    jit compilations — with fused rounds on device drafts and with the
+    host-draft round alike. (RL training serves the same problem set every
     epoch; a bucket flip or shape wobble here would recompile mid-run.)
     """
     params = make_params(DENSE)
@@ -308,12 +315,11 @@ def test_steady_state_serve_never_recompiles(device_draft):
     # floors guarantee stable kernel geometry). While windows are still
     # FILLING the forest legitimately grows and may cross a pow2 bucket
     # — that is warmup, not steady state.
-    eng = _engine(params, DENSE, device_draft=device_draft,
-                  fuse="auto", window_size=4)
+    eng = _engine(params, DENSE, host=device == "off", window_size=4)
 
     def serve_epoch(epoch):
         eng.begin_iteration(epoch)
-        outs, _ = eng.generate_continuous(
+        outs, _ = eng.generate(
             PROMPTS, PIDS, slots=4, max_new_tokens=LIMITS,
             key=jax.random.key(11 + epoch),
         )
@@ -327,25 +333,42 @@ def test_steady_state_serve_never_recompiles(device_draft):
         serve_epoch(epoch)
         assert eng.compile_count() == n0, (
             f"epoch {epoch} recompiled in steady state "
-            f"(device_draft={device_draft})"
+            f"(device drafts {device})"
         )
 
 
 def test_fused_strictly_fewer_transfers_per_round():
     """The fused round's host↔device traffic: one budget upload + one
-    packed result download per round, vs the unfused query/block/flag
-    uploads and multi-array downloads."""
+    packed result download per round, vs the host-draft round's
+    block/budget/flag uploads and two-array download. Crossings outside
+    the round are counted apart: per admission chunk the prefill's two
+    uploads, the cache-row scatter and the first-token download; for
+    fused rounds the round state's upload (5 arrays), each admission's
+    state write (5) and each forest/roots sync (1)."""
+    from repro.core.scheduler import Request
+    from repro.core.spec_engine import RolloutStats
+
     params = make_params(DENSE)
     per_round = {}
     for fuse in ("on", "off"):
-        eng = _engine(params, DENSE, fuse=fuse)
+        eng = _engine(params, DENSE, host=fuse == "off")
         eng.begin_iteration(0)
         eng.generate(PROMPTS, PIDS, max_new_tokens=LIMITS,
                      key=jax.random.key(5))
         eng.begin_iteration(1)
-        from repro.core.spec_engine import RolloutStats
-        from repro.core.scheduler import Request
+        calls = {}
+        for name in ("_get_prefill", "_get_admit_state",
+                     "_get_forget_matches"):
+            def counted(*a, name=name, get=getattr(eng, name)):
+                fn = get(*a)
 
+                def call(*args):
+                    calls[name] = calls.get(name, 0) + 1
+                    return fn(*args)
+
+                return call
+
+            setattr(eng, name, counted)
         reqs = [
             Request(rid=i, problem_id=PIDS[i], prompt=list(PROMPTS[i]),
                     max_new_tokens=LIMITS[i])
@@ -353,9 +376,14 @@ def test_fused_strictly_fewer_transfers_per_round():
         ]
         stats = RolloutStats()
         list(eng.serve(reqs, slots=4, key=jax.random.key(7), stats=stats))
-        per_round[fuse] = (stats.n_h2d + stats.n_d2h) / max(
-            stats.n_rounds, 1
-        )
+        outside = 4 * calls["_get_prefill"]
+        if fuse == "on":
+            outside += (5 + 5 * calls["_get_admit_state"]
+                        + calls["_get_forget_matches"])
+        assert stats.n_rounds > 0
+        per_round[fuse] = (
+            stats.n_h2d + stats.n_d2h - outside) / stats.n_rounds
+    assert per_round == {"on": 2.0, "off": 5.0}, per_round
     assert per_round["on"] < per_round["off"], per_round
 
 
@@ -370,7 +398,7 @@ def test_fused_rounds_draft_with_the_xla_core(monkeypatch):
         raise AssertionError("the main path reached pallas_call")
 
     monkeypatch.setattr(pl, "pallas_call", refuse)
-    eng = _engine(make_params(DENSE), DENSE, fuse="auto")
+    eng = _engine(make_params(DENSE), DENSE)
     _, _, st = _two_epochs(eng, mode="continuous")
     assert st.n_drafted > 0
     assert eng._fused_jit and not eng._verify_jit
@@ -397,8 +425,8 @@ def _scoped_instructions(hlo_text):
 @pytest.mark.parametrize("program", ["fused", "verify"])
 def test_round_phases_are_named_scopes(program):
     """The compiled round names its phases in every instruction's
-    ``op_name``: the fused program all four, the unfused verify (the
-    same ``verify_step``) forward, accept and commit. The layer scan's
+    ``op_name``: the fused program all four, the host-draft round's
+    verify (the same ``verify_step``) forward, accept and commit. The layer scan's
     ``while`` falls under ``forward``."""
     from repro.core.fused_round import build_fused_round, make_state
     from repro.kernels.suffix_match import ops as sm_ops
@@ -410,7 +438,7 @@ def test_round_phases_are_named_scopes(program):
     key = jax.random.key(0)
     if program == "fused":
         fn = build_fused_round(
-            DENSE, K=K, micro_rounds=1, temperature=0.0, eos_token=1,
+            DENSE, K=K, temperature=0.0, eos_token=1,
             recurrent=False, attn_impl="xla", min_match=1,
         )
         forest, _ = sm_ops.pack_forest([])
@@ -420,7 +448,7 @@ def test_round_phases_are_named_scopes(program):
                 np.zeros(B, np.int32), key)
         want = set(PHASES)
     else:
-        fn = _engine(params, DENSE, fuse="off")._get_verify(K)
+        fn = _engine(params, DENSE, host=True)._get_verify(K)
         args = (params, cache, np.zeros((B, K + 1), np.int32),
                 np.zeros(B, np.int32), np.ones(B, bool), key)
         want = {"forward", "accept", "commit"}

@@ -108,7 +108,7 @@ def _engines(spec=True, max_new=30):
 def test_continuous_parity_with_lockstep_greedy():
     lock, cont = _engines(spec=True)
     out0, st0 = lock.generate(PROMPTS, PIDS, key=jax.random.key(5))
-    out1, st1 = cont.generate_continuous(
+    out1, st1 = cont.generate(
         PROMPTS, PIDS, slots=2, key=jax.random.key(11)
     )
     assert out0 == out1, "continuous batching must be lossless at T=0"
@@ -177,7 +177,7 @@ def test_continuous_makespan_beats_lockstep_waves_on_long_tail():
             outs_lock[i] = oi
 
     cont = mk()
-    outs_cont, st = cont.generate_continuous(
+    outs_cont, st = cont.generate(
         prompts, pids, slots=slots, max_new_tokens=lengths,
         key=jax.random.key(7),
     )
@@ -197,9 +197,8 @@ def test_per_row_token_limits_are_exact():
     limits = [1, 2, 7, 1, 3]
     o0, _ = lock.generate(PROMPTS, PIDS, max_new_tokens=limits,
                           key=jax.random.key(4))
-    o1, _ = cont.generate_continuous(PROMPTS, PIDS, slots=2,
-                                     max_new_tokens=limits,
-                                     key=jax.random.key(4))
+    o1, _ = cont.generate(PROMPTS, PIDS, slots=2,
+                          max_new_tokens=limits, key=jax.random.key(4))
     assert o0 == o1
     for o, lim in zip(o0, limits):
         assert len(o) <= lim
@@ -207,14 +206,14 @@ def test_per_row_token_limits_are_exact():
 
 def test_generate_continuous_default_slots_and_stats():
     _, eng = _engines(spec=False, max_new=12)
-    outs, st = eng.generate_continuous(PROMPTS, PIDS, key=jax.random.key(1))
+    outs, st = eng.generate(PROMPTS, PIDS, key=jax.random.key(1))
     assert len(outs) == len(PROMPTS)
     assert st.per_row_rounds.shape == (len(PROMPTS),)
     assert st.n_toks_emitted == sum(len(o) for o in outs)
     assert all(len(o) <= 12 for o in outs)
     # effective batch never exceeds the pool
     _, eng2 = _engines(spec=False, max_new=12)
-    _, st2 = eng2.generate_continuous(
+    _, st2 = eng2.generate(
         PROMPTS, PIDS, slots=2, key=jax.random.key(1),
         collect_effective_batch=True,
     )

@@ -191,9 +191,10 @@ def test_batched_sessions_host_fallback_for_request_scope():
 
 def test_engine_device_draft_parity(tiny_dense):
     """Device drafting must not change emitted tokens (T=0 losslessness)
-    and must actually take the batched device path."""
+    against the host sessions on the same drafts, and must actually take
+    the batched device path."""
     import jax
-    from conftest import make_params
+    from conftest import host_drafts, make_params
     from repro.core.spec_engine import EngineConfig, SpecEngine
 
     params = make_params(tiny_dense)
@@ -203,8 +204,10 @@ def test_engine_device_draft_parity(tiny_dense):
         eng = SpecEngine(
             params, tiny_dense,
             EngineConfig(max_new_tokens=24, max_draft=4,
-                         block_buckets=(0, 2, 4), device_draft=mode),
+                         block_buckets=(0, 2, 4)),
         )
+        if mode == "off":
+            host_drafts(eng)
         for it in range(2):  # second pass drafts from first-pass history
             eng.begin_iteration(it)
             outs[(mode, it)], _ = eng.generate(
@@ -334,8 +337,7 @@ def test_engine_fused_with_chunked_forest_parity(tiny_dense):
         eng = SpecEngine(
             params, tiny_dense,
             EngineConfig(max_new_tokens=20, max_draft=4,
-                         block_buckets=(0, 2, 4), device_draft="on",
-                         fuse_rounds="on"),
+                         block_buckets=(0, 2, 4)),
             drafter=SuffixDrafter(
                 DrafterConfig(scope="problem", min_match=1,
                               forest_layout=layout)
